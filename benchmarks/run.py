@@ -20,6 +20,8 @@ import argparse
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 
 BENCHES = [
     ("fig3", "benchmarks.bench_scheduling"),
@@ -39,6 +41,7 @@ def main() -> None:
     ap.add_argument("--only", default="",
                     help="comma-separated bench tags to run")
     args = ap.parse_args()
+    enable_compile_cache()
     only = set(t for t in args.only.split(",") if t)
 
     print("name,us_per_call,derived")

@@ -35,12 +35,13 @@ import argparse
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.paper_cnn import PAPER_CNN_CIFAR10
 from repro.data import (apply_imbalance, dirichlet_partition,
                         sort_and_partition, synthetic_image_dataset,
                         train_test_split)
 from repro.fl import FederatedTrainer, FLConfig, MultiCellTrainer
-from repro.faults import FaultConfig
+from repro.faults import LOSSY_UPLINK, FaultConfig
 from repro.models import build_model
 
 
@@ -78,6 +79,7 @@ def main():
                     help="enable repro.obs and stream per-round metric "
                          "records to this JSONL file")
     args = ap.parse_args()
+    enable_compile_cache()
 
     ds = synthetic_image_dataset(num_classes=args.classes, num_per_class=120,
                                  image_size=16, seed=args.seed)
@@ -99,12 +101,7 @@ def main():
     import dataclasses as dc
     cfg = dc.replace(PAPER_CNN_CIFAR10.reduced(), num_classes=args.classes)
     model = build_model(cfg)
-    faults = FaultConfig()
-    if args.lossy:
-        faults = FaultConfig(outage_prob=0.2, dropout_prob=0.1,
-                             deadline_miss_prob=0.05, corrupt_prob=0.1,
-                             reshadow_std_db=4.0, outage_slack=0.2,
-                             clip_delta_norm=25.0, backfill=True)
+    faults = LOSSY_UPLINK if args.lossy else FaultConfig()
     overrides = {k: getattr(args, k) for k in
                  ("outage_prob", "dropout_prob", "corrupt_prob",
                   "reshadow_std_db", "clip_delta_norm")

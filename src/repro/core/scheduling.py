@@ -81,6 +81,28 @@ class Schedule:
         return int(self.mask.sum())
 
 
+# Candidates whose WEMD lies within TIE_TOL * (1 + |min|) of the
+# minimum are tied, and the lowest device index among them wins.  A tie
+# in exact arithmetic (two candidate sets with equal WEMD) is otherwise
+# decided by rounding, which differs between the numpy
+# solvers and the batched jax engine (dot vs. reduce order, emulated
+# f64 on TPU); the margin is far above their ulp-level disagreement.
+TIE_TOL = 1e-12
+
+
+def tie_tol(w_min):
+    """Tie margin around a candidate minimum (numpy or jax values; an
+    infinite minimum gives an infinite margin: all candidates tie)."""
+    return TIE_TOL * (abs(w_min) + 1.0)
+
+
+def argmin_tied(w: np.ndarray) -> int:
+    """Flat index of the lowest-index candidate tied with the minimum."""
+    w = np.ravel(w)
+    m = w.min()
+    return int(np.flatnonzero(w <= m + tie_tol(m))[0])
+
+
 def _make_schedule(prob: Problem, mask, iters, name) -> Schedule:
     mask = np.asarray(mask, bool)
     w = WE.wemd_of_set(prob.p_dev, mask, prob.global_dist,
@@ -113,7 +135,7 @@ def greedy_scheduling(prob: Problem) -> Schedule:
         w_new = WE.wemd_add_candidates(p_sum, size, prob.p_dev,
                                        prob.global_dist, prob.class_weights)
         w_new = np.where(cand, w_new, np.inf)
-        k = int(np.argmin(w_new))               # max WEMD reduction
+        k = argmin_tied(w_new)                  # max WEMD reduction
         # sampling-variance gain of going S -> S+1
         sv_gain = sigma_b * ((1.0 / np.sqrt(size) if size else np.inf)
                              - 1.0 / np.sqrt(size + 1))
@@ -163,7 +185,7 @@ def fscd(prob: Problem, max_inner: int = 200) -> Schedule:
             # bandwidth feasibility of each swap
             bw_new = used - bw[in_idx][:, None] + bw[out_idx][None, :]
             w_swap = np.where(bw_new <= prob.total_bw + 1e-9, w_swap, np.inf)
-            i, j = np.unravel_index(np.argmin(w_swap), w_swap.shape)
+            i, j = np.unravel_index(argmin_tied(w_swap), w_swap.shape)
             if w_swap[i, j] >= w_cur - 1e-12:
                 break                            # local optimum
             vi, vj = in_idx[i], out_idx[j]
@@ -260,8 +282,8 @@ def solve_many(problems: Sequence[Problem], algorithm: str = "fscd",
 
     ``pallas`` routes the jax backend's f32 candidate scans through the
     Pallas ``wemd_swap`` / ``wemd_add`` kernels (None = auto: only on a
-    TPU backend).  Scheduling decisions still go through the exact-f64
-    top-K re-evaluation, so masks stay bitwise-equal to numpy.
+    TPU backend).  Scheduling decisions still go through the f64
+    re-evaluation, so masks stay equal to numpy's.
 
     ``obs`` is a ``repro.obs.Obs`` facade: when enabled, the dispatch
     runs under a ``solve_many.<backend>`` span and updates per-backend

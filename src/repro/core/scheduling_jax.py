@@ -1,7 +1,7 @@
 """Batched JAX backend for the P1 schedulers (GS / FSCD).
 
 ``solve_many_jax`` replicates the numpy solvers' arithmetic op-for-op in
-float64 (inside a local ``jax.experimental.enable_x64`` scope) so its
+float64 (inside a local ``jax.enable_x64(True)`` scope) so its
 masks coincide with the per-problem numpy path, while amortizing the
 solver over a problem axis:
 
@@ -13,17 +13,17 @@ solver over a problem axis:
     *phases* — after each phase the still-unconverged lanes are
     compacted on the host so the batch shrinks as lanes converge.  The
     swap matrix is scanned on member-compacted rows in float32 and the
-    top-K candidates re-evaluated with numpy's exact float64 op order,
-    with ties broken by device index exactly like ``np.argmin``.  The
-    ``best``/early-exit bookkeeping of the numpy outer loop is replayed
-    on the host from per-lane results.
+    top-K candidates re-evaluated with numpy's float64 op order (the
+    whole matrix when more than K candidates may tie the minimum), with
+    ties broken by device index exactly like ``scheduling.argmin_tied``.
+    The ``best``/early-exit bookkeeping of the numpy outer loop is
+    replayed on the host from per-lane results.
 
-The float64 decisions make this the parity backend on CPU.  The float32
-Pallas kernels in ``repro.kernels`` (``wemd_swap`` / ``wemd_add``) can
-be routed in for the candidate *scan* (``pallas=True``, or automatically
+The float64 decisions make the masks equal numpy's.  The float32 Pallas
+kernels in ``repro.kernels`` (``wemd_swap`` / ``wemd_add``) can be
+routed in for the candidate *scan* (``pallas=True``, or automatically
 on a TPU backend): the kernels produce the f32 swap/add matrices and the
-exact-f64 top-K re-evaluation still makes every accept/swap decision, so
-the selected masks remain bitwise-equal to numpy.  On a single
+float64 re-evaluation still makes every accept/swap decision.  On a single
 CPU core the batched FSCD path roughly matches the numpy loop (the
 lanes are data-parallel, so the win scales with cores/accelerator);
 batched GS is ~8x even single-core.
@@ -32,6 +32,8 @@ from __future__ import annotations
 
 from typing import List, Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core import scheduling as SCH
@@ -39,21 +41,40 @@ from repro.core import scheduling as SCH
 _LANE_BUCKET = 32          # min lane-batch granule (see _bucket)
 
 
-def _enable_x64():
-    from jax.experimental import enable_x64
-    return enable_x64()
-
-
 def _use_pallas(pallas) -> bool:
     """Kernel routing: explicit override wins, else auto-on-TPU.  The
-    Pallas kernels compute the f32 candidate matrices; ranking still goes
-    through the exact-f64 top-K re-evaluation, so the selected masks stay
-    bitwise-equal to the numpy solvers (verified in tests with the
-    interpret-mode kernels on CPU)."""
+    Pallas kernels compute the f32 candidate matrices; every decision
+    still goes through the f64 re-evaluation, so the selected masks equal
+    the numpy solvers' (verified in tests with the interpret-mode kernels
+    on CPU, and by ``chip_smoke.py`` on the chip)."""
     if pallas is not None:
         return bool(pallas)
-    import jax
     return jax.default_backend() == "tpu"
+
+
+def _pick_tied(w64, valid, index):
+    """``scheduling.argmin_tied`` over candidate subsets, batched: per
+    row, the lowest ``index`` among valid candidates within ``tie_tol``
+    of the minimum, and its value.  Rows without a valid candidate give
+    (+inf, an index past every candidate).  w64 / valid / index [B, N]."""
+    w = jnp.where(valid, w64, jnp.inf)
+    wmin = w.min(axis=1)
+    tied = valid & (w <= (wmin + SCH.tie_tol(wmin))[:, None])
+    sel = jnp.where(tied, index, jnp.iinfo(jnp.int32).max).min(axis=1)
+    wsel = jnp.where(tied & (index == sel[:, None]), w, jnp.inf).min(axis=1)
+    return wsel, sel
+
+
+def _topk_covers(w32, valid, cw, K):
+    """True when, in every row, the K smallest f32 candidates include all
+    that may tie the f64 minimum.  The f32 scans err by under ~1e-6 of
+    (value + sum_c cw_c); candidates within 1e-5 of that above the f32
+    minimum are the ones that may tie."""
+    w = jnp.where(valid, w32, jnp.inf)
+    m = w.min(axis=1)
+    margin = 1e-5 * (jnp.abs(m) + cw.sum(axis=1).astype(w32.dtype))
+    n = (valid & (w <= (m + margin)[:, None])).sum(axis=1)
+    return (n <= K).all()
 
 
 # ---------------------------------------------------------------------------
@@ -62,13 +83,11 @@ def _use_pallas(pallas) -> bool:
 
 def _gs_batch_impl(p_dev, gd, cw, sigma, batch_size, min_bw, total_bw,
                    use_pallas=False):
-    import jax
-    import jax.numpy as jnp
-
     B, V, C = p_dev.shape
     feas = (min_bw >= 0) & (min_bw <= total_bw[:, None])
     sigma_b = sigma / jnp.sqrt(batch_size)
     K = min(16, V)
+    dev_idx = jnp.broadcast_to(jnp.arange(V, dtype=jnp.int32), (B, V))
 
     def cond(carry):
         return carry[4].any()
@@ -79,33 +98,36 @@ def _gs_batch_impl(p_dev, gd, cw, sigma, batch_size, min_bw, total_bw,
         act = active & cand.any(axis=1)
         iters = iters + act.astype(jnp.int32)
         size = jnp.sum(mask, axis=1).astype(p_dev.dtype)
-        if use_pallas:
-            # f32 Pallas add-candidate row, then exact f64 (numpy op
-            # order) re-evaluation of the top K — ranking error ~1e-6
-            # vs candidate gaps O(1e-3), so the true argmin is inside K
-            from repro.kernels import ops
-            w32 = ops.wemd_add(p_sum, p_dev, gd, cw, size)
-            w32 = jnp.where(cand, w32, jnp.float32(jnp.inf))
-            _, topk = jax.lax.top_k(-w32, K)                     # [B,K]
-            p_k = jnp.take_along_axis(p_dev, topk[:, :, None], 1)
-            new_k = (p_sum[:, None, :] + p_k) / (size[:, None, None] + 1.0)
-            w64k = jnp.einsum("bkc,bc->bk",
-                              jnp.abs(new_k - gd[:, None, :]), cw)
-            valid_k = jnp.take_along_axis(cand, topk, 1)
-            w64k = jnp.where(valid_k, w64k, jnp.inf)
-            wk = w64k.min(axis=1)
-            # numpy argmin tie-break: min device index among exact minima
-            k = jnp.minimum(
-                jnp.where(valid_k & (w64k == wk[:, None]), topk,
-                          V).min(axis=1), V - 1)
-        else:
+
+        def add_full():
             # wemd_add_candidates, batched, all-f64
             new = (p_sum[:, None, :] + p_dev) / (size[:, None, None] + 1.0)
             w_new = jnp.einsum("bvc,bc->bv",
                                jnp.abs(new - gd[:, None, :]), cw)
-            w_new = jnp.where(cand, w_new, jnp.inf)
-            k = jnp.argmin(w_new, axis=1)
-            wk = jnp.take_along_axis(w_new, k[:, None], 1)[:, 0]
+            return _pick_tied(w_new, cand, dev_idx)
+
+        if use_pallas:
+            # f32 Pallas add-candidate row, then the f64 (numpy op order)
+            # re-evaluation of the top K, or of the whole row when the
+            # top K cannot hold every candidate that may tie the minimum
+            from repro.kernels import ops
+            w32 = ops.wemd_add(p_sum, p_dev, gd, cw, size)
+            _, topk = jax.lax.top_k(-jnp.where(cand, w32, jnp.inf), K)
+
+            def add_topk():
+                p_k = jnp.take_along_axis(p_dev, topk[:, :, None], 1)
+                new_k = (p_sum[:, None, :] + p_k) \
+                    / (size[:, None, None] + 1.0)
+                w64k = jnp.einsum("bkc,bc->bk",
+                                  jnp.abs(new_k - gd[:, None, :]), cw)
+                return _pick_tied(w64k, jnp.take_along_axis(cand, topk, 1),
+                                  topk)
+
+            wk, k = jax.lax.cond(_topk_covers(w32, cand, cw, K), add_topk,
+                                 add_full)
+        else:
+            wk, k = add_full()
+        k = jnp.minimum(k, V - 1)
         inv_sqrt = jnp.where(size > 0,
                              1.0 / jnp.sqrt(jnp.where(size > 0, size, 1.0)),
                              jnp.inf)
@@ -140,16 +162,13 @@ def _gs_batch_impl(p_dev, gd, cw, sigma, batch_size, min_bw, total_bw,
 #     W[l,r,j] = sum_c cw_c * | A[l,r,c] + B[l,j,c] |,
 #     A = (p_sum - p_member)/S - gd,   B = p_dev/S,
 # and ties broken by the *device-order* flat index (min vi*V+vj among
-# exact minima) — precisely numpy's argmin-over-[in,out] tie-break, so
-# the member-row permutation cannot change the selected swap.
+# the tied minima) — precisely numpy's ``argmin_tied`` over [in, out],
+# so the member-row permutation cannot change the selected swap.
 
 
 def _fscd_phase_impl(p_dev, gd, cw, bw, feas, total_bw, s_lane,
                      members, mask, p_sum, used, w_cur, act, iters,
                      max_inner, phase_steps, use_pallas=False):
-    import jax
-    import jax.numpy as jnp
-
     L, V, C = p_dev.shape
     R = members.shape[1]
     sf = s_lane.astype(p_dev.dtype)
@@ -168,10 +187,14 @@ def _fscd_phase_impl(p_dev, gd, cw, bw, feas, total_bw, s_lane,
         live = act & (iters < max_inner)
         iters = iters + live.astype(jnp.int32)
         p_mem = jnp.take_along_axis(p_dev, members[:, :, None], 1)  # [L,R,C]
-        # float32 scan of the full [R, V] swap matrix, then an exact
-        # float64 re-evaluation (numpy's op order) of the K best
-        # candidates — f32 ranking error is ~1e-6 while candidate gaps
-        # are O(1e-3), so the true minimum is always inside the top K
+        bw_mem = jnp.take_along_axis(bw, members, 1)
+        bw_new = (used[:, None, None] - bw_mem[:, :, None]) + bw[:, None, :]
+        ok = (valid_r[:, :, None] & (~mask & feas)[:, None, :]
+              & (bw_new <= total_bw[:, None, None] + 1e-9)).reshape(L, R * V)
+        # float32 scan of the full [R, V] swap matrix, then the float64
+        # re-evaluation (numpy's op order) of the K best candidates, or of
+        # the whole matrix when the top K cannot hold every candidate
+        # that may tie the minimum
         if use_pallas:
             # dense [L,V,V] f32 swap matrix from the Pallas kernel,
             # gathered down to the member rows
@@ -185,29 +208,36 @@ def _fscd_phase_impl(p_dev, gd, cw, bw, feas, total_bw, s_lane,
             w32 = jnp.sum(jnp.abs(a[:, :, None, :] + b[:, None, :, :])
                           * cw[:, None, None, :].astype(f32),
                           axis=-1)                              # [L,R,V]
-        bw_mem = jnp.take_along_axis(bw, members, 1)
-        bw_new = (used[:, None, None] - bw_mem[:, :, None]) + bw[:, None, :]
-        ok = valid_r[:, :, None] & (~mask & feas)[:, None, :] \
-            & (bw_new <= total_bw[:, None, None] + 1e-9)
-        wm32 = jnp.where(ok, w32, f32(jnp.inf)).reshape(L, R * V)
-        _, flat_rv = jax.lax.top_k(-wm32, K)                     # [L,K]
-        r_k = flat_rv // V
-        j_k = flat_rv % V
-        vi_k = jnp.take_along_axis(members, r_k, 1)
-        p_i_k = jnp.take_along_axis(p_dev, vi_k[:, :, None], 1)  # [L,K,C]
-        p_j_k = jnp.take_along_axis(p_dev, j_k[:, :, None], 1)
-        base = (p_sum[:, None, :] - p_i_k) + p_j_k
-        w64 = jnp.sum(jnp.abs(base / sf_safe[:, None, None]
-                              - gd[:, None, :]) * cw[:, None, :], axis=-1)
-        valid_k = jnp.take_along_axis(ok.reshape(L, R * V), flat_rv, 1)
-        w64 = jnp.where(valid_k, w64, jnp.inf)
-        wmin = w64.min(axis=1)
-        # numpy tie-break: first (vi, vj) in device order among minima
-        flat_dev = vi_k * jnp.int32(V) + j_k.astype(jnp.int32)
-        flatmin = jnp.where(valid_k & (w64 == wmin[:, None]), flat_dev,
-                            jnp.int32(V * V)).min(axis=1)
+        w32 = w32.reshape(L, R * V)
+        # numpy's tie-break: (vi, vj) in device order
+        flat_dev = (members[:, :, None] * jnp.int32(V)
+                    + jnp.arange(V, dtype=jnp.int32)).reshape(L, R * V)
+
+        def swap_w64(p_i, p_j):
+            base = (p_sum[:, None, :] - p_i) + p_j
+            return jnp.sum(jnp.abs(base / sf_safe[:, None, None]
+                                   - gd[:, None, :]) * cw[:, None, :],
+                           axis=-1)
+
+        def swap_full():
+            p_i = jnp.repeat(p_mem, V, axis=1)                  # [L,R*V,C]
+            p_j = jnp.tile(p_dev, (1, R, 1))
+            return _pick_tied(swap_w64(p_i, p_j), ok, flat_dev)
+
+        _, flat_rv = jax.lax.top_k(-jnp.where(ok, w32, jnp.inf), K)
+
+        def swap_topk():
+            vi_k = jnp.take_along_axis(members, flat_rv // V, 1)
+            p_i_k = jnp.take_along_axis(p_dev, vi_k[:, :, None], 1)
+            p_j_k = jnp.take_along_axis(p_dev, (flat_rv % V)[:, :, None], 1)
+            return _pick_tied(swap_w64(p_i_k, p_j_k),
+                              jnp.take_along_axis(ok, flat_rv, 1),
+                              jnp.take_along_axis(flat_dev, flat_rv, 1))
+
+        wmin, flatmin = jax.lax.cond(_topk_covers(w32, ok, cw, K),
+                                     swap_topk, swap_full)
         vi = jnp.minimum(flatmin // V, V - 1)
-        vj = jnp.minimum(flatmin % V, V - 1)
+        vj = flatmin % V
         rsel = (members == vi[:, None]) & valid_r
         rpos = jnp.argmax(rsel, axis=1)
         improve = wmin < w_cur - 1e-12
@@ -237,7 +267,6 @@ _JIT_CACHE: dict = {}
 
 
 def _jitted(name, fn, static_argnums=()):
-    import jax
     if name not in _JIT_CACHE:
         _JIT_CACHE[name] = jax.jit(fn, static_argnums=static_argnums)
     return _JIT_CACHE[name]
@@ -273,7 +302,7 @@ def solve_many_gs(problems: Sequence[SCH.Problem],
                   pallas: bool | None = None) -> List[SCH.Schedule]:
     st = _stack(problems)
     up = _use_pallas(pallas)
-    with _enable_x64():
+    with jax.enable_x64(True):
         fn = _jitted(f"gs_p{int(up)}", _gs_batch_impl,
                      static_argnums=(7,))
         masks, iters = fn(st["p_dev"], st["gd"], st["cw"], st["sigma"],
@@ -346,7 +375,7 @@ def solve_many_fscd(problems: Sequence[SCH.Problem],
         # slowest lane is done
         alive = np.arange(L)
         up = _use_pallas(pallas)
-        with _enable_x64():
+        with jax.enable_x64(True):
             fn = _jitted(f"fscd_phase_p{int(up)}", _fscd_phase_impl,
                          static_argnums=(14, 15, 16))
             while alive.size:

@@ -82,3 +82,12 @@ class FaultConfig:
         return (self.outage_prob > 0 or self.reshadow_std_db > 0
                 or self.dropout_prob > 0 or self.deadline_miss_prob > 0
                 or self.corrupt_prob > 0)
+
+
+# The lossy-uplink mix of ``examples/wireless_fl.py --lossy``: every
+# fault cause fires, and every server defense (NaN/Inf guard, norm clip,
+# backfill) is armed.
+LOSSY_UPLINK = FaultConfig(outage_prob=0.2, dropout_prob=0.1,
+                           deadline_miss_prob=0.05, corrupt_prob=0.1,
+                           reshadow_std_db=4.0, outage_slack=0.2,
+                           clip_delta_norm=25.0, backfill=True)

@@ -7,8 +7,6 @@ over a leading cell axis (same op order as ``aggregate``, so the two
 agree bitwise for a single cell)."""
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -48,8 +46,7 @@ def select_device(device_params, v: int):
     return jax.tree.map(lambda x: x[v], device_params)
 
 
-def make_finalize_core(tau: int, eta: float, cell_axis: str = "auto",
-                       donate: str = "auto"):
+def make_finalize_core(tau: int, eta: float, cell_axis: str = "auto"):
     """Fused server-side finalize, batched over cells.
 
     Returns ``core(params, dev_params, deltas, w, active)`` where every
@@ -74,9 +71,8 @@ def make_finalize_core(tau: int, eta: float, cell_axis: str = "auto",
     so a C-cell finalize is bitwise-identical to C standalone ones —
     the CPU default), ``"vmap"`` batches it for accelerators.
 
-    ``donate="auto"`` donates the dev_params/deltas buffers to the
-    computation on accelerator backends (they are dead after finalize);
-    CPU keeps them, where jax buffer donation is unsupported."""
+    Nothing is donated: no output has the [C, V, ...] shape of
+    ``dev_params`` / ``deltas``, so XLA could not reuse their buffers."""
 
     def one_cell(args):
         params, dev_params, deltas, w, active = args
@@ -111,15 +107,11 @@ def make_finalize_core(tau: int, eta: float, cell_axis: str = "auto",
     if cell_axis not in ("scan", "vmap"):
         raise ValueError(f"cell_axis must be auto|vmap|scan, "
                          f"got {cell_axis!r}")
-    kw = {}
-    if donate == "auto" and jax.default_backend() != "cpu":
-        kw["donate_argnums"] = (1, 2)
-
     if cell_axis == "vmap":
         return jax.jit(jax.vmap(
-            lambda p, dp, d, w, a: one_cell((p, dp, d, w, a))), **kw)
+            lambda p, dp, d, w, a: one_cell((p, dp, d, w, a))))
 
-    @partial(jax.jit, **kw)
+    @jax.jit
     def core(params_c, dev_params_c, deltas_c, w_c, active_c):
         return jax.lax.map(one_cell, (params_c, dev_params_c, deltas_c,
                                       w_c, active_c))

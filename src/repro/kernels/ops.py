@@ -1,8 +1,9 @@
 """Jit'd public wrappers over the Pallas kernels.
 
-On CPU (this container) the kernels run in ``interpret=True`` mode — the
-kernel body executes as Python/jnp, validating the exact TPU code path.
-On a real TPU backend ``interpret=False`` compiles to Mosaic.
+On the CPU backend (tests) the kernels run in ``interpret=True`` mode —
+the kernel body executes as Python/jnp, validating the exact TPU code
+path.  On any other backend ``interpret=False`` compiles to Mosaic, so a
+run meant for the chip fails loudly instead of interpreting.
 """
 from __future__ import annotations
 
@@ -18,7 +19,7 @@ from repro.kernels.wemd_swap import wemd_add_pallas, wemd_swap_pallas
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 def attention(q, k, v, *, causal: bool = True, window: int = 0):

@@ -33,7 +33,8 @@ import jax            # noqa: E402
 from repro.configs import get_config, list_archs                 # noqa: E402
 from repro.fl.distributed import (make_prefill_step,             # noqa: E402
                                   make_serve_step, make_train_step)
-from repro.launch.mesh import make_production_mesh               # noqa: E402
+from repro.launch.mesh import (PRODUCTION_DEVICE_KIND,          # noqa: E402
+                               make_production_mesh)
 from repro.launch.shapes import SHAPES, applicable_shapes, CACHE_PAD  # noqa: E402
 from repro.launch import shardings as SH                         # noqa: E402
 from repro.roofline import (collective_bytes_from_hlo,           # noqa: E402
@@ -134,6 +135,7 @@ def lower_and_compile(arch: str, shape_name: str, multi_pod: bool,
         bytes_per_device=corrected["bytes"],
         collective_bytes_per_device=corrected["collective_bytes"],
         chips=chips,
+        device_kind=PRODUCTION_DEVICE_KIND,
         model_flops=mflops,
     )
 

@@ -9,6 +9,10 @@ from __future__ import annotations
 
 import jax
 
+# chip of the production pods, as ``jax.Device.device_kind`` names it
+# (the dry-run's placeholder devices are CPUs)
+PRODUCTION_DEVICE_KIND = "TPU v5 lite"
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)

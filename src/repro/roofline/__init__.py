@@ -1,8 +1,8 @@
 from repro.roofline.analysis import (  # noqa: F401
-    HBM_BW,
-    LINK_BW,
-    PEAK_FLOPS,
+    CHIP_PEAKS,
+    ChipPeaks,
     Roofline,
+    chip_peaks,
     collective_bytes_from_hlo,
     count_params,
     model_flops,

@@ -1,7 +1,8 @@
 """Roofline analysis from compiled dry-run artifacts (brief §Roofline).
 
-TPU v5e constants (per chip): 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s
-per ICI link.
+Per-chip peaks come from ``CHIP_PEAKS``, keyed by the device kind JAX
+reports (``jax.Device.device_kind``); a kind missing from the table is
+an error, never a default.
 
   compute term    = HLO_FLOPs / (chips * peak)
   memory term     = HLO_bytes / (chips * hbm_bw)
@@ -21,9 +22,32 @@ import dataclasses
 import re
 from typing import Dict
 
-PEAK_FLOPS = 197e12        # bf16 per chip
-HBM_BW = 819e9             # bytes/s per chip
-LINK_BW = 50e9             # bytes/s per ICI link
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    flops: float               # bf16 FLOP/s per chip
+    hbm_bw: float              # HBM bytes/s per chip
+    link_bw: float             # bytes/s per ICI link
+    source: str
+
+
+CHIP_PEAKS: Dict[str, ChipPeaks] = {
+    # 1,600 Gbit/s of ICI per chip over 4 links = 50 GB/s a link
+    "TPU v5 lite": ChipPeaks(
+        flops=197e12, hbm_bw=819e9, link_bw=50e9,
+        source='Google Cloud documentation, "TPU v5e"'),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Published peaks of one chip of ``device_kind``."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add "
+            f"them to CHIP_PEAKS with their source") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -92,10 +116,11 @@ class Roofline:
 
 def roofline_terms(flops_per_device: float, bytes_per_device: float,
                    collective_bytes_per_device: float, chips: int,
-                   model_flops: float = 0.0) -> Roofline:
-    compute = flops_per_device / PEAK_FLOPS
-    memory = bytes_per_device / HBM_BW
-    collective = collective_bytes_per_device / LINK_BW
+                   device_kind: str, model_flops: float = 0.0) -> Roofline:
+    peaks = chip_peaks(device_kind)
+    compute = flops_per_device / peaks.flops
+    memory = bytes_per_device / peaks.hbm_bw
+    collective = collective_bytes_per_device / peaks.link_bw
     terms = {"compute": compute, "memory": memory, "collective": collective}
     bottleneck = max(terms, key=terms.get)
     total_hlo_flops = flops_per_device * chips

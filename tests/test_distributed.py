@@ -16,7 +16,9 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 def run_sub(script: str):
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
-    env.pop("JAX_PLATFORMS", None)
+    # the children want forced host devices; on a machine with a chip the
+    # parent holds it, and a child reaching for it would contend or hang
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", script], env=env,
                          capture_output=True, text=True, timeout=900)
     assert out.returncode == 0, out.stdout + "\n" + out.stderr

@@ -3,8 +3,12 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.launch.shapes import SHAPES
-from repro.roofline import (collective_bytes_from_hlo, count_params,
-                            model_flops, roofline_terms)
+import pytest
+
+from repro.roofline import (chip_peaks, collective_bytes_from_hlo,
+                            count_params, model_flops, roofline_terms)
+
+V5E = "TPU v5 lite"
 
 
 def test_collective_parser():
@@ -24,13 +28,23 @@ def test_collective_parser():
 
 
 def test_roofline_bottleneck_selection():
-    r = roofline_terms(1e15, 1e9, 1e9, chips=256, model_flops=2.56e17)
+    r = roofline_terms(1e15, 1e9, 1e9, chips=256, device_kind=V5E,
+                       model_flops=2.56e17)
     assert r.bottleneck == "compute"
     assert abs(r.flops_ratio - 1.0) < 1e-6
-    r2 = roofline_terms(1e9, 1e12, 1e9, chips=256)
+    r2 = roofline_terms(1e9, 1e12, 1e9, chips=256, device_kind=V5E)
     assert r2.bottleneck == "memory"
-    r3 = roofline_terms(1e9, 1e9, 1e12, chips=256)
+    r3 = roofline_terms(1e9, 1e9, 1e12, chips=256, device_kind=V5E)
     assert r3.bottleneck == "collective"
+
+
+def test_peaks_keyed_by_device_kind():
+    assert chip_peaks(V5E).flops == 197e12
+    assert chip_peaks(V5E).hbm_bw == 819e9
+    with pytest.raises(ValueError, match="no published peaks"):
+        chip_peaks("cpu")
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline_terms(1e9, 1e9, 1e9, chips=1, device_kind="TPU v4")
 
 
 def test_count_params_sane():

@@ -61,6 +61,51 @@ def test_solve_many_matches_numpy(algorithm, V):
         assert np.isclose(e.objective, g.objective, rtol=0, atol=1e-9)
 
 
+def deployment_problem(rng, V, C, max_classes, b=32):
+    """A P1 instance shaped like the paper deployment's: each device
+    holds 1..max_classes classes, its distribution is a histogram of b
+    sampled labels (multiples of 1/b), and the class weights are one
+    G-hat.  Many candidate sets then tie in exact arithmetic."""
+    p_dev = np.zeros((V, C))
+    for v in range(V):
+        cls = rng.choice(C, size=rng.integers(1, max_classes + 1),
+                         replace=False)
+        labels = rng.choice(cls, size=b)
+        p_dev[v] = np.bincount(labels, minlength=C) / b
+    counts = rng.integers(80, 120, C)
+    return S.Problem(
+        p_dev=p_dev, global_dist=counts / counts.sum(),
+        class_weights=np.full(C, float(rng.uniform(0.5, 3.0))),
+        sigma=float(rng.uniform(2.0, 6.0)), batch_size=b,
+        min_bw=rng.uniform(0.4, 1.6, V), total_bw=V * 0.5)
+
+
+@pytest.mark.parametrize("pallas", [False, True])
+@pytest.mark.parametrize("algorithm", ["gs", "fscd"])
+@pytest.mark.parametrize("V,C,max_classes", [(24, 10, 2), (40, 4, 1)])
+def test_solve_many_matches_numpy_on_ties(V, C, max_classes, algorithm,
+                                          pallas):
+    """Exact-arithmetic ties are decided by ``argmin_tied`` in both
+    backends, not by how each rounds.  One-class devices tie by the
+    dozen, more than the f32 top K holds: the jax engine then
+    re-evaluates every candidate."""
+    rng = np.random.default_rng(77 + V)
+    probs = [deployment_problem(rng, V, C, max_classes)
+             for _ in range(RNG_PROBLEMS)]
+    numpy_fn = {"gs": S.greedy_scheduling, "fscd": S.fscd}[algorithm]
+    got = S.solve_many(probs, algorithm, backend="jax", pallas=pallas)
+    for t, (p, g) in enumerate(zip(probs, got)):
+        assert np.array_equal(numpy_fn(p).mask, g.mask), (algorithm, t)
+
+
+def test_argmin_tied_takes_lowest_index_within_margin():
+    w = np.array([3.0, 1.0 + 5e-13, 2.0, 1.0, 1.0 - 5e-13])
+    assert S.argmin_tied(w) == 1
+    assert S.argmin_tied(np.array([2.0, 1.0, 1.0 + 1e-9])) == 1
+    assert S.argmin_tied(np.array([[np.inf, 4.0], [4.0, 5.0]])) == 1
+    assert S.argmin_tied(np.full(3, np.inf)) == 0
+
+
 def test_solve_many_numpy_backend_identity():
     rng = np.random.default_rng(5)
     probs = [random_problem(rng, 12) for _ in range(4)]
