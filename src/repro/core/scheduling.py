@@ -286,9 +286,11 @@ def solve_many(problems: Sequence[Problem], algorithm: str = "fscd",
     re-evaluation, so masks stay equal to numpy's.
 
     ``obs`` is a ``repro.obs.Obs`` facade: when enabled, the dispatch
-    runs under a ``solve_many.<backend>`` span and updates per-backend
-    call + iteration counters (None = the process-wide default, which
-    is off unless ``repro.obs.enable_default()`` armed it).
+    runs under a ``solve_many.<backend>`` span, each device->host pull
+    of the jax backend under a ``schedule.pull`` span, and per-backend
+    call + iteration counters are updated (None = the process-wide
+    default, which is off unless ``repro.obs.enable_default()`` armed
+    it).
     """
     problems = list(problems)
     if algorithm not in SOLVE_MANY_ALGORITHMS:
@@ -300,11 +302,11 @@ def solve_many(problems: Sequence[Problem], algorithm: str = "fscd",
         from repro.obs import DEFAULT as obs
     if not obs.enabled:
         return _solve_many_impl(problems, algorithm, backend, max_inner,
-                                pallas)
+                                pallas, obs)
     with obs.span(f"solve_many.{backend}", algorithm=algorithm,
                   batch=len(problems)):
         scheds = _solve_many_impl(problems, algorithm, backend,
-                                  max_inner, pallas)
+                                  max_inner, pallas, obs)
     m = obs.metrics
     m.counter(f"sched.solve_many_calls.{backend}").inc()
     m.counter("sched.problems_total").inc(len(problems))
@@ -315,7 +317,7 @@ def solve_many(problems: Sequence[Problem], algorithm: str = "fscd",
 
 def _solve_many_impl(problems: List[Problem], algorithm: str,
                      backend: str, max_inner: int,
-                     pallas: Optional[bool]) -> List[Schedule]:
+                     pallas: Optional[bool], obs) -> List[Schedule]:
     if backend == "numpy" or algorithm == "cd":
         fn = {"gs": greedy_scheduling, "fscd": fscd,
               "cd": coordinate_descent}[algorithm]
@@ -324,8 +326,9 @@ def _solve_many_impl(problems: List[Problem], algorithm: str,
         raise ValueError(f"unknown backend {backend!r}")
     from repro.core import scheduling_jax as SJ
     if algorithm == "gs":
-        return SJ.solve_many_gs(problems, pallas=pallas)
-    return SJ.solve_many_fscd(problems, max_inner=max_inner, pallas=pallas)
+        return SJ.solve_many_gs(problems, pallas=pallas, obs=obs)
+    return SJ.solve_many_fscd(problems, max_inner=max_inner, pallas=pallas,
+                              obs=obs)
 
 
 # ---------------------------------------------------------------------------

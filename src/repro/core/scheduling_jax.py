@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import scheduling as SCH
+from repro.obs import DISABLED
 
 _LANE_BUCKET = 32          # min lane-batch granule (see _bucket)
 
@@ -299,7 +300,10 @@ def _stack(problems: Sequence[SCH.Problem]):
 
 
 def solve_many_gs(problems: Sequence[SCH.Problem],
-                  pallas: bool | None = None) -> List[SCH.Schedule]:
+                  pallas: bool | None = None,
+                  obs=DISABLED) -> List[SCH.Schedule]:
+    """GS over the batch in one dispatch and one device->host pull
+    (``obs``'s ``schedule.pull`` span)."""
     st = _stack(problems)
     up = _use_pallas(pallas)
     with jax.enable_x64(True):
@@ -308,7 +312,7 @@ def solve_many_gs(problems: Sequence[SCH.Problem],
         masks, iters = fn(st["p_dev"], st["gd"], st["cw"], st["sigma"],
                           st["batch_size"], st["min_bw"], st["total_bw"],
                           up)
-        masks, iters = np.asarray(masks), np.asarray(iters)
+        masks, iters = obs.pull((masks, iters), "schedule.pull")
     return [SCH._make_schedule(p, masks[b], int(iters[b]), "GS")
             for b, p in enumerate(problems)]
 
@@ -325,7 +329,11 @@ def _bucket(n: int) -> int:
 def solve_many_fscd(problems: Sequence[SCH.Problem],
                     max_inner: int = 200,
                     phase_steps: int = 4,
-                    pallas: bool | None = None) -> List[SCH.Schedule]:
+                    pallas: bool | None = None,
+                    obs=DISABLED) -> List[SCH.Schedule]:
+    """FSCD over the batch in phases of ``phase_steps`` descent steps:
+    one dispatch and one device->host pull (``obs``'s ``schedule.pull``
+    span) per phase."""
     from repro.core import wemd as WE
 
     st = _stack(problems)
@@ -389,7 +397,7 @@ def solve_many_fscd(problems: Sequence[SCH.Problem],
                          members[sel], masks[sel], p_sum[sel], used[sel],
                          w_cur[sel], act_in, iters[sel],
                          int(max_inner), int(phase_steps), up)
-                o = [np.asarray(x)[:n] for x in out]
+                o = [x[:n] for x in obs.pull(out, "schedule.pull")]
                 members[alive], masks[alive], p_sum[alive] = o[0], o[1], o[2]
                 used[alive], w_cur[alive] = o[3], o[4]
                 act[alive], iters[alive] = o[5], o[6]

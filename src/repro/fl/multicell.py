@@ -179,21 +179,24 @@ class MultiCellTrainer:
         # round core + finalize core so C=1 executes the exact programs
         # FederatedTrainer runs (bitwise parity) and C>1 reuses one
         # compilation (C standalone trainers would compile C copies)
-        self._core = self.obs.instrument_jit("round_core",
-                                             self.cells[0]._round_core)
+        self._core = self.cells[0]._round_core
+        self._finalize_core = self.cells[0]._finalize_core
         for cell in self.cells[1:]:
-            cell._round_core = self.cells[0]._round_core
+            cell._round_core = self._core
             cell._sigma_all = self.cells[0]._sigma_all
-            cell._finalize_core = self.cells[0]._finalize_core
-        self._finalize_core = self.obs.instrument_jit(
-            "finalize_core", self.cells[0]._finalize_core)
+            cell._finalize_core = self._finalize_core
         # params stay stacked [C, ...] across rounds (the round core and
         # finalize consume/produce the stack directly); cells get their
-        # slices back through one jitted dispatch per round
+        # slices back through one jitted dispatch per round, the
+        # program ``jit_unstack_params``
         self._params_c = jax.tree.map(lambda *xs: jnp.stack(xs),
                                       *[cell.params for cell in self.cells])
-        self._unstack_params = jax.jit(lambda t: tuple(
-            jax.tree.map(lambda x, c=c: x[c], t) for c in range(C)))
+
+        def unstack_params(t):
+            return tuple(jax.tree.map(lambda x, c=c: x[c], t)
+                         for c in range(C))
+
+        self._unstack_params = jax.jit(unstack_params)
         self._pad_cache = _PadCache()
         self._algorithm = "gs" if cfg.scheduler == "fedcgd-gs" else "fscd"
         self.solve_many_calls = 0        # scheduling dispatches issued
@@ -265,10 +268,11 @@ class MultiCellTrainer:
                               for cs in cell_states])[:, None]
             bstar_cv = min_bandwidth(cells[0].payload, cfg.deadline_s,
                                      rx_cv, noise)
-            preps = [cell._prep_from_channel(j, av, ai, gains_cv[c],
-                                             bstar_cv[c])
-                     for c, (cell, (av, ai))
-                     in enumerate(zip(cells, avails))]
+            with obs.span("prep.batches"):
+                preps = [cell._prep_from_channel(j, av, ai, gains_cv[c],
+                                                 bstar_cv[c])
+                         for c, (cell, (av, ai))
+                         in enumerate(zip(cells, avails))]
             n_av = [len(p.avail_idx) for p in preps]
             vmax = max(n_av)
 
@@ -284,8 +288,8 @@ class MultiCellTrainer:
             keys_c = jnp.stack([p.subkey for p in preps])
             dev_params_c, losses_c, sigma_c, deltas_c, norms_c, fin_c = \
                 self._core(self._params_c, batches_c, keys_c)
-            lh, sh, nh, fh = jax.device_get((losses_c, sigma_c, norms_c,
-                                             fin_c))
+            lh, sh, nh, fh = obs.pull((losses_c, sigma_c, norms_c, fin_c),
+                                      "core.pull")
             self.last_round_host_syncs += 1
 
         with obs.span("schedule", cells=C):
@@ -356,7 +360,7 @@ class MultiCellTrainer:
                 self._params_c, dev_params_c, deltas_c, w_cv, active)
             self._params_c = newp_c
             cell_params = self._unstack_params(newp_c)
-            norms_h = jax.device_get(norms_fc)
+            norms_h = obs.pull(norms_fc, "finalize.pull")
             self.last_round_host_syncs += 1
 
             recs = []

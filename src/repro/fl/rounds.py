@@ -183,21 +183,16 @@ class FederatedTrainer:
         self._obs_sched_iters = 0                    # last round, for obs
 
         self._local_update = make_local_update(self._loss, cfg.eta, cfg.tau)
-        # instrument_jit is the identity when obs is disabled; enabled,
-        # it counts XLA compiles + compile seconds per core
-        self._round_core = self.obs.instrument_jit(
-            "round_core", make_round_core(self._loss, self._sigma_one,
-                                          cfg.eta, cfg.tau))
+        self._round_core = make_round_core(self._loss, self._sigma_one,
+                                           cfg.eta, cfg.tau)
         self._sigma_all = jax.jit(jax.vmap(self._sigma_one,
                                            in_axes=(None, 0)))
         # fused finalize hot path: Eq. 2 weighted sum (the op order of
         # ``server.aggregate``) + the Eq. 12 centered-gradient norms in
         # ONE cell-batched dispatch (zero-upload cells keep their params
         # through an in-graph select)
-        self._finalize_core = self.obs.instrument_jit(
-            "finalize_core", make_finalize_core(cfg.tau, cfg.eta))
-        self._eval_batch = self.obs.instrument_jit(
-            "eval", jax.jit(self._eval_fn))
+        self._finalize_core = make_finalize_core(cfg.tau, cfg.eta)
+        self._eval_batch = jax.jit(self._eval_fn)
         self.last_round_host_syncs = 0       # device->host pulls between
         #   local update and aggregation (fused round contract: <= 3)
 
@@ -402,7 +397,9 @@ class FederatedTrainer:
         rx_power = self.cell.received_power(gains)
         bstar = min_bandwidth(self.payload, cfg.deadline_s, rx_power,
                               self.cell.params.noise_psd_w)
-        return self._prep_from_channel(j, avail, avail_idx, gains, bstar)
+        with self.obs.span("prep.batches"):
+            return self._prep_from_channel(j, avail, avail_idx, gains,
+                                           bstar)
 
     def _post_core(self, prep: RoundPrep, dev_losses: np.ndarray,
                    sigma_v: np.ndarray) -> None:
@@ -581,7 +578,7 @@ class FederatedTrainer:
         self.params = jax.tree.map(lambda x: x[0], newp_c)
         norms = None
         if active:       # the only device->host pull of finalize
-            norms = jax.device_get(norms_c)[0]
+            norms = self.obs.pull(norms_c, "finalize.pull")[0]
             self.last_round_host_syncs += 1
         return self._finalize_host(j, prep, sched, st, norms, dev_losses)
 
@@ -612,8 +609,8 @@ class FederatedTrainer:
                     jax.tree.map(lambda x: x[None], self.params),
                     jax.tree.map(lambda x: x[None], prep.batches),
                     jnp.stack([prep.subkey]))
-            lh, sh, nh, fh = jax.device_get((losses_c, sigma_c, norms_c,
-                                             fin_c))
+            lh, sh, nh, fh = obs.pull((losses_c, sigma_c, norms_c, fin_c),
+                                      "core.pull")
             dev_losses, sigma_v, delta_norms = (
                 np.asarray(x[0], dtype=np.float64) for x in (lh, sh, nh))
             finite = np.asarray(fh[0])

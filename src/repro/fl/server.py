@@ -49,7 +49,8 @@ def select_device(device_params, v: int):
 def make_finalize_core(tau: int, eta: float, cell_axis: str = "auto"):
     """Fused server-side finalize, batched over cells.
 
-    Returns ``core(params, dev_params, deltas, w, active)`` where every
+    Returns ``finalize_cells(params, dev_params, deltas, w, active)``
+    (the program ``jit_finalize_cells``) where every
     argument carries a leading [C] cell axis: ``params`` [C, ...] the
     pre-round models, ``dev_params`` / ``deltas`` [C, V, ...] the round
     core's outputs (with any sanitizer replacements already scattered
@@ -108,12 +109,14 @@ def make_finalize_core(tau: int, eta: float, cell_axis: str = "auto"):
         raise ValueError(f"cell_axis must be auto|vmap|scan, "
                          f"got {cell_axis!r}")
     if cell_axis == "vmap":
-        return jax.jit(jax.vmap(
-            lambda p, dp, d, w, a: one_cell((p, dp, d, w, a))))
+        def finalize_cells(params, dev_params, deltas, w, active):
+            return one_cell((params, dev_params, deltas, w, active))
+
+        return jax.jit(jax.vmap(finalize_cells))
 
     @jax.jit
-    def core(params_c, dev_params_c, deltas_c, w_c, active_c):
+    def finalize_cells(params_c, dev_params_c, deltas_c, w_c, active_c):
         return jax.lax.map(one_cell, (params_c, dev_params_c, deltas_c,
                                       w_c, active_c))
 
-    return core
+    return finalize_cells

@@ -12,9 +12,12 @@ The observability substrate for the FL engine:
   * ``profile_rounds`` — ``jax.profiler`` trace of N steady rounds.
 
 Span names emitted by the trainers: ``round`` (whole round), and its
-phases ``prep`` / ``core`` / ``schedule`` / ``upload`` / ``finalize``,
-plus ``solve_many.<backend>`` inside scheduling.  Metric names are
-documented in ROADMAP.md's Observability section.
+phases ``prep`` / ``core`` / ``schedule`` / ``upload`` / ``finalize``;
+inside them ``prep.batches`` (batch gather, transfer, key split),
+``solve_many.<backend>``, and the device->host pulls made through
+``Obs.pull``: ``core.pull``, ``schedule.pull``, ``finalize.pull``.
+Each span is also a profiler annotation ``fl.<name>``.  Metric names
+are documented in ROADMAP.md's Observability section.
 """
 from repro.obs.config import ObsConfig  # noqa: F401
 from repro.obs.core import (DEFAULT, DISABLED, Obs,  # noqa: F401

@@ -2,11 +2,17 @@
 
 Trainers hold an ``Obs`` built by ``from_config(FLConfig.obs)``.  With
 observability off (the default) that is the shared ``DISABLED``
-singleton: ``span()`` returns the no-op null span, ``instrument_jit``
-returns the callable unchanged, and every emit helper is guarded by
+singleton: ``span()`` returns the no-op null span, ``pull()`` is plain
+``jax.device_get``, and every emit helper is guarded by
 ``if obs.enabled`` at the call site — the fault-free round is
 bitwise-identical to the uninstrumented trainer and pays no measurable
 per-round cost.
+
+XLA compiles are counted by one process-wide listener on JAX's
+backend-compile event, registered when the first facade is enabled: it
+feeds ``xla.compiles_total`` and ``xla.compile_seconds_total`` of every
+enabled facade alive, so programs built anywhere in the process (round
+core, finalize, the scheduler's f64 programs, eager ops) are counted.
 
 ``DEFAULT`` is the process-wide facade used by library code that has no
 trainer handle (e.g. ``core.scheduling.solve_many`` when called without
@@ -14,14 +20,41 @@ trainer handle (e.g. ``core.scheduling.solve_many`` when called without
 """
 from __future__ import annotations
 
-import functools
-import time
+import weakref
 from typing import Dict, List, Optional
+
+import jax
 
 from repro.obs.config import ObsConfig
 from repro.obs.metrics import Registry
 from repro.obs.sinks import ConsoleSink, JSONLSink, MemorySink
 from repro.obs.tracing import Tracer
+
+# JAX's event around every backend compile or persistent-cache load
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+_compile_watchers: "weakref.WeakSet[Obs]" = weakref.WeakSet()
+_compile_listener_registered = False
+
+
+def _on_duration_event(event: str, duration: float, **_) -> None:
+    if event != COMPILE_EVENT:
+        return
+    for obs in list(_compile_watchers):
+        if obs.enabled:
+            obs.metrics.counter("xla.compiles_total").inc()
+            obs.metrics.counter("xla.compile_seconds_total").inc(duration)
+
+
+def _watch_compiles(obs: "Obs") -> None:
+    """Count the process's XLA compiles into ``obs`` (the listener is
+    registered with JAX once, on the first enabled facade)."""
+    global _compile_listener_registered
+    if not _compile_listener_registered:
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_duration_event)
+        _compile_listener_registered = True
+    _compile_watchers.add(obs)
 
 
 class Obs:
@@ -31,6 +64,8 @@ class Obs:
         self.metrics = registry if registry is not None else Registry()
         self.tracer = Tracer(self.metrics, enabled=enabled)
         self.sinks = list(sinks)
+        if enabled:
+            _watch_compiles(self)
 
     # ------------------------------------------------------------------
     # tracing
@@ -40,38 +75,13 @@ class Obs:
     def trace(self, name: str):
         return self.tracer.trace(name)
 
-    # ------------------------------------------------------------------
-    # XLA compile tracking
-    def instrument_jit(self, name: str, fn):
-        """Wrap a jitted callable to count compiles and compile seconds.
-
-        A call that grows the function's executable cache is counted as
-        a compile and its whole wall time attributed to
-        ``xla.compile_seconds_total`` (dispatch is asynchronous, so on a
-        compile call the trace+lower+compile time dominates; steady
-        calls add nothing).  When disabled, returns ``fn`` unchanged —
-        zero indirection on the hot path."""
-        if not self.enabled:
-            return fn
-        cache_size = getattr(fn, "_cache_size", None)
-        reg = self.metrics
-
-        @functools.wraps(fn)
-        def wrapped(*args, **kwargs):
-            n0 = cache_size() if cache_size is not None else -1
-            t0 = time.perf_counter()
-            out = fn(*args, **kwargs)
-            reg.counter(f"xla.calls.{name}").inc()
-            if cache_size is not None and cache_size() > n0:
-                dt = time.perf_counter() - t0
-                reg.counter("xla.compiles_total").inc()
-                reg.counter(f"xla.compiles.{name}").inc()
-                reg.counter("xla.compile_seconds_total").inc(dt)
-                reg.counter(f"xla.compile_seconds.{name}").inc(dt)
-            return out
-
-        wrapped.__wrapped__ = fn
-        return wrapped
+    def pull(self, tree, name: str):
+        """``jax.device_get(tree)`` under the span ``name``: the host's
+        wait for the device work behind ``tree`` plus the copy.  Every
+        device->host pull of a round goes through here (``core.pull``,
+        ``finalize.pull``, ``schedule.pull``)."""
+        with self.tracer.span(name):
+            return jax.device_get(tree)
 
     # ------------------------------------------------------------------
     # sinks
@@ -84,16 +94,23 @@ class Obs:
 
         ``phases`` maps each depth-1 span name to its summed seconds;
         ``round_s`` is the enclosing depth-0 ``round`` span's duration,
-        so consumers can check that the phases cover the round."""
+        so consumers can check that the phases cover the round;
+        ``sched_pulls`` counts the scheduler's device->host pulls (its
+        ``schedule.pull`` spans), which the trainers' host-sync count
+        leaves out."""
         phases: Dict[str, float] = {}
         round_s = None
+        sched_pulls = 0
         for s in self.tracer.drain():
             if s.depth == 0 and s.name == "round":
                 round_s = s.seconds
             elif s.depth == 1:
                 phases[s.name] = phases.get(s.name, 0.0) + s.seconds
+            if s.name == "schedule.pull":
+                sched_pulls += 1
         out = dict(record)
         out.setdefault("kind", "round")
+        out["sched_pulls"] = sched_pulls
         if phases:
             out["phases"] = phases
         if round_s is not None:
@@ -126,6 +143,7 @@ def enable_default(sinks=()) -> Obs:
     """Arm the process-wide ``DEFAULT`` facade (idempotent)."""
     DEFAULT.enabled = True
     DEFAULT.tracer.enabled = True
+    _watch_compiles(DEFAULT)
     for s in sinks:
         DEFAULT.sinks.append(s)
     return DEFAULT

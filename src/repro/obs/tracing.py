@@ -7,6 +7,14 @@ accumulate across the run), and a ``SpanRecord`` is appended to the
 per-round buffer that ``drain()`` empties — the trainers drain once per
 round to attach a ``phases`` breakdown to the round record.
 
+An enabled span is also a ``jax.profiler.TraceAnnotation`` named
+``fl.<name>`` (``fl.round``, ``fl.core.pull``, ...): under the profiler
+it lands on the host's timeline, on the device trace's clock, so an idle
+gap of the device can be read against what the host was doing.  The
+prefix keeps the engine's ``round`` span apart from a caller's own
+``round`` step annotations.  Outside a profiler trace the annotation
+costs well under a microsecond.
+
 Disabled tracers return the module-level ``NULL_SPAN`` singleton whose
 ``__enter__``/``__exit__`` do nothing: the cost of an off span is one
 attribute check, no allocation, no clock read.
@@ -17,6 +25,11 @@ import dataclasses
 import functools
 from time import perf_counter
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
+
+# prefix of every span's profiler annotation
+ANNOTATION_PREFIX = "fl."
 
 
 @dataclasses.dataclass
@@ -42,20 +55,23 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "name", "tags", "t0")
+    __slots__ = ("_tracer", "name", "tags", "t0", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, tags: Optional[Dict]):
         self._tracer = tracer
         self.name = name
         self.tags = tags
+        self._annotation = TraceAnnotation(ANNOTATION_PREFIX + name)
 
     def __enter__(self):
+        self._annotation.__enter__()
         self._tracer._depth += 1
         self.t0 = perf_counter()
         return self
 
     def __exit__(self, *exc):
         seconds = perf_counter() - self.t0
+        self._annotation.__exit__(*exc)
         tr = self._tracer
         tr._depth -= 1
         tr.records.append(SpanRecord(self.name, tr._depth, seconds,

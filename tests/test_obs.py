@@ -4,7 +4,7 @@ instrumentation.
 Key contracts:
   * obs disabled (the default) is free: ``from_config`` returns the
     shared ``DISABLED`` singleton, spans are the no-op ``NULL_SPAN``,
-    ``instrument_jit`` is the identity, and a fault-free run is
+    ``pull`` is plain ``jax.device_get``, and a fault-free run is
     bitwise-identical (history AND params) to an obs-enabled run on
     both scheduler backends;
   * every round emits one record whose ``phases`` (prep/core/schedule/
@@ -14,8 +14,12 @@ Key contracts:
   * round records carry ``g_refresh_errors_round`` plus the deprecated
     ``g_refresh_errors`` alias (same per-round value; the trainer
     attribute stays cumulative);
-  * the jit-wrapper hook counts compiles on cache growth only — steady
-    rounds at a fixed shape add calls but no compiles.
+  * the compile listener counts every program the process builds (the
+    scheduler's too) — steady rounds at a fixed shape add none;
+  * enabled spans are profiler annotations ``fl.<name>`` on the host's
+    timeline, each ``*.pull`` inside its phase; obs off writes none;
+  * the scheduler's device->host pulls go through ``Obs.pull``: one for
+    GS, one per FSCD phase, with unchanged masks.
 """
 import dataclasses
 import math
@@ -44,6 +48,7 @@ LOSSY = FaultConfig(outage_prob=0.3, dropout_prob=0.2,
 PHASES = ("prep", "core", "schedule", "upload", "finalize")
 
 RECORD_KEYS = ("round", "kind", "phases", "round_s", "host_syncs",
+               "sched_pulls",
                "upload_bytes", "sched_iterations", "num_uploaded",
                "num_failed", "failure_causes", "num_sanitized",
                "num_clipped", "num_backfilled",
@@ -185,10 +190,9 @@ def test_disabled_facade_is_shared_and_null():
     assert from_config(ObsConfig()) is DISABLED
     assert from_config(None) is DISABLED
     assert DISABLED.span("anything") is NULL_SPAN
-
-    def fn():
-        return 1
-    assert DISABLED.instrument_jit("fn", fn) is fn
+    assert DISABLED.pull(jax.numpy.arange(3), "core.pull").tolist() == \
+        [0, 1, 2]
+    assert not DISABLED.metrics.histograms
 
 
 def test_obs_config_validation():
@@ -335,8 +339,8 @@ def test_g_refresh_errors_round_and_alias(micro_world, monkeypatch):
 
 
 def test_compile_metrics_steady_state(micro_world):
-    """The jit hook counts compiles only on cache growth: round 0 pays
-    them, later rounds at the same shape add calls, not compiles."""
+    """The compile listener counts programs as they are built: round 0
+    pays them, later rounds at the same shape add calls, not compiles."""
     tr = make_trainer(micro_world, obs=ObsConfig(enabled=True))
     tr.run_round(0)
     m = tr.obs.metrics
@@ -348,7 +352,7 @@ def test_compile_metrics_steady_state(micro_world):
         tr.run_round(j)
     assert m.counter("xla.compiles_total").value == compiles0
     assert m.counter("xla.compile_seconds_total").value == seconds0
-    assert m.counter("xla.calls.round_core").value == 3
+    assert m.histogram("span.core.pull").count == 3
 
 
 def test_solve_many_scheduler_metrics(micro_world):
@@ -410,3 +414,145 @@ def test_profile_rounds_smoke(micro_world, tmp_path):
     assert os.path.isdir(out)
     with pytest.raises(ValueError):
         profile_rounds(tr, 0, tmp_path / "t2")
+
+
+def test_compile_listener_counts_scheduler_programs(monkeypatch):
+    """The listener sees programs no trainer wraps: a fresh GS program
+    counts into every enabled facade, never into ``DISABLED``."""
+    from repro.core import scheduling as S
+    from repro.core import scheduling_jax as SJ
+    monkeypatch.setattr(SJ, "_JIT_CACHE", {})     # force a fresh build
+    obs = Obs(enabled=True)
+    S.solve_many(_sched_problems(np.random.default_rng(5), 2, 12), "gs",
+                 backend="jax", obs=obs)
+    m = obs.metrics
+    assert m.counter("xla.compiles_total").value >= 1
+    assert m.counter("xla.compile_seconds_total").value > 0
+    assert not DISABLED.metrics.counters
+
+
+# ---------------------------------------------------------------------------
+# the scheduler's device->host pulls
+
+
+def _sched_problems(rng, n, V, C=6):
+    from repro.core import scheduling as S
+    return [S.Problem(p_dev=rng.dirichlet(np.full(C, 0.4), size=V),
+                      global_dist=rng.dirichlet(np.full(C, 3.0)),
+                      class_weights=rng.uniform(0.5, 1.5, C),
+                      sigma=float(rng.uniform(2.0, 6.0)), batch_size=32,
+                      min_bw=rng.uniform(0.4, 1.6, V), total_bw=V * 0.5)
+            for _ in range(n)]
+
+
+@pytest.mark.parametrize("algorithm", ["gs", "fscd"])
+def test_scheduler_pulls_one_per_dispatch(monkeypatch, algorithm):
+    """GS pulls once per ``solve_many`` call, FSCD once per phase, each
+    under ``schedule.pull``; masks equal numpy's with obs on and off."""
+    from repro.core import scheduling as S
+    from repro.core import scheduling_jax as SJ
+    dispatches = []
+    real = SJ._jitted
+
+    def counting(name, fn, static_argnums=()):
+        jitted = real(name, fn, static_argnums)
+
+        def call(*args):
+            dispatches.append(name)
+            return jitted(*args)
+        return call
+
+    monkeypatch.setattr(SJ, "_jitted", counting)
+    probs = _sched_problems(np.random.default_rng(11), 6, 16)
+    want = S.solve_many(probs, algorithm, backend="numpy")
+    obs = Obs(enabled=True, sinks=[MemorySink(4)])
+    with obs.span("round"):
+        got = S.solve_many(probs, algorithm, backend="jax", obs=obs)
+    n_dispatch = len(dispatches)
+    off = S.solve_many(probs, algorithm, backend="jax", obs=DISABLED)
+    for w, g, o in zip(want, got, off):
+        assert np.array_equal(w.mask, g.mask)
+        assert np.array_equal(w.mask, o.mask)
+        assert w.iterations == g.iterations == o.iterations
+    pulls = obs.metrics.histogram("span.schedule.pull").count
+    if algorithm == "gs":
+        assert pulls == n_dispatch == 1
+    else:
+        assert pulls == n_dispatch > 1
+    assert obs.round_record({"round": 0})["sched_pulls"] == pulls
+
+
+def test_round_records_count_scheduler_pulls(micro_world):
+    model, train, test, parts = micro_world
+    mc = MultiCellTrainer(model, train, test, parts,
+                          micro_cfg(obs=ObsConfig(enabled=True)))
+    for j in range(2):
+        mc.run_round(j)
+    recs = [r for r in mc.obs.records() if r["kind"] == "multicell_round"]
+    pulls = mc.obs.metrics.histogram("span.schedule.pull").count
+    assert sum(r["sched_pulls"] for r in recs) == pulls >= 2
+    assert mc.obs.metrics.histogram("span.core.pull").count == 2
+    assert mc.obs.metrics.histogram("span.finalize.pull").count == 2
+    assert mc.obs.metrics.histogram("span.prep.batches").count == 2
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's timeline
+
+# each inner span and the phase span it lies in
+INNER_SPANS = {"fl.prep.batches": "fl.prep", "fl.core.pull": "fl.core",
+               "fl.schedule.pull": "fl.schedule",
+               "fl.finalize.pull": "fl.finalize"}
+
+
+def _host_events(trainer, outdir):
+    """[(name, start_ns, end_ns)] of the host's events in a profiler
+    trace of rounds 1 and 2, taken with the benchmark's trace options
+    (no Python tracer, host tracer at its lowest level)."""
+    import glob
+    import os
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    opts.advanced_configuration = {"tpu_trace_mode": "TRACE_COMPUTE"}
+    trainer.run_round(0)            # builds the programs outside the trace
+    jax.profiler.start_trace(str(outdir), profiler_options=opts)
+    try:
+        for j in (1, 2):
+            trainer.run_round(j)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(outdir), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    pd = jax.profiler.ProfileData.from_file(path)
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+            for plane in pd.planes if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events]
+
+
+def test_spans_on_the_profiler_host_timeline(micro_world, tmp_path):
+    model, train, test, parts = micro_world
+    mc = MultiCellTrainer(model, train, test, parts,
+                          micro_cfg(obs=ObsConfig(enabled=True)))
+    events = _host_events(mc, tmp_path)
+    names = [n for n, _, _ in events]
+    for name in ("fl.prep", "fl.prep.batches", "fl.core", "fl.core.pull",
+                 "fl.schedule", "fl.schedule.pull", "fl.finalize",
+                 "fl.finalize.pull"):
+        assert name in names, name
+    assert names.count("fl.round") == 2
+    for inner, outer in INNER_SPANS.items():
+        spans = [(a, b) for n, a, b in events if n == outer]
+        for n, a, b in events:
+            if n == inner:
+                assert any(lo <= a and b <= hi for lo, hi in spans), n
+    # a profiler step annotation named "round" stays the caller's own
+    assert "round" not in names
+
+
+def test_no_annotations_with_obs_off(micro_world, tmp_path):
+    model, train, test, parts = micro_world
+    mc = MultiCellTrainer(model, train, test, parts, micro_cfg())
+    events = _host_events(mc, tmp_path)
+    assert events                       # the trace itself was taken
+    assert not [n for n, _, _ in events if n.startswith("fl.")]
