@@ -38,8 +38,14 @@ def _gn(x, scale, bias, groups=8, eps=1e-5):
 
 
 def _maxpool2(x):
-    return jax.lax.reduce_window(
-        x, -jnp.inf, jax.lax.max, (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
+    """2x2 max-pool, stride 2, "VALID": an odd H or W drops its last row
+    or column.  A reshape and a max, which XLA compiles to plain
+    reductions; a ``reduce_window`` would bring a ``select_and_scatter``
+    into the gradient, the slowest op of the round core on a TPU.  Where
+    a window's maximum is tied the gradient is split equally."""
+    n, h, w, c = x.shape
+    x = x[:, :h - h % 2, :w - w % 2]
+    return x.reshape(n, h // 2, 2, w // 2, 2, c).max(axis=(2, 4))
 
 
 def _dropout(x, rate, rng):
