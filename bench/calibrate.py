@@ -34,16 +34,16 @@ def reading(cfg, ref, traffic, seed, fault=None, control=False):
     with check.P1Recorder(S, flip=(fault == "flip_mask")) as p1:
         checked, prog = cell.checked_rounds(wd, p1)
     w0 = jax.device_get(wd.weights)
-    images, labels = wd.images, wd.labels
+    inputs, labels = wd.inputs, wd.labels
     del wd
     gc.collect()
-    refs = cell.reference_rounds(ref, cfg, traffic, w0, images, labels,
+    refs = cell.reference_rounds(ref, cfg, traffic, w0, inputs, labels,
                                  checked, p1.solved)
     out = [dict(cell.gaps(w0, prog, refs), seed=seed, fault=fault,
                 side="program",
                 mask_mismatches=check.mask_mismatches(p1.solved))]
     if control:
-        ctrl = cell.reference_rounds(ref, cfg, traffic, w0, images, labels,
+        ctrl = cell.reference_rounds(ref, cfg, traffic, w0, inputs, labels,
                                      checked, p1.solved, dtype=jnp.bfloat16,
                                      precision=jax.lax.Precision.DEFAULT)
         per_round = [[c[i] for c in ctrl] for i in range(len(ctrl[0]))]

@@ -180,12 +180,12 @@ def run(workload: str, seed: int, seconds: float, traced: bool,
               sorted(rng.choice(len(window_solved), k, replace=False))]
     solved_checked = p1.solved[:n_checked]
     w0 = jax.device_get(wd.weights)
-    images, labels = wd.images, wd.labels
+    inputs, labels = wd.inputs, wd.labels
     del wd, tr, p1
     gc.collect()
 
     t_ref = time.perf_counter()
-    refs = reference_rounds(ref, cfg, traffic, w0, images, labels, checked,
+    refs = reference_rounds(ref, cfg, traffic, w0, inputs, labels, checked,
                             solved_checked)
     numbers = gaps(w0, prog_rounds, refs)
     numbers["mask_mismatches"] = float(check.mask_mismatches(
@@ -279,7 +279,7 @@ def checked_rounds(wd, p1):
     return checked, prog_rounds
 
 
-def reference_rounds(ref, cfg, traffic, w0, images, labels, checked,
+def reference_rounds(ref, cfg, traffic, w0, inputs, labels, checked,
                      solved, dtype=None, precision=None) -> list:
     """Per cell, the reference followed over the checked rounds, with
     the uploads the reference solver picks on the program's P1
@@ -303,7 +303,7 @@ def reference_rounds(ref, cfg, traffic, w0, images, labels, checked,
             rounds.append({"takes": np.stack(dev_takes), "keys": k[:n],
                            "upload": check.sched_ref.SOLVERS[alg](inst)})
         out.append(reference.follow(
-            ref, cfg, w0, images, labels, rounds, traffic["eta"],
+            ref, cfg, w0, inputs, labels, rounds, traffic["eta"],
             dtype=dtype or jnp.float32,
             precision=precision or jax.lax.Precision.HIGHEST,
             chunk=cfg["reference_chunk"]))

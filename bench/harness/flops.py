@@ -1,5 +1,10 @@
-"""Operations and bytes of a CNN round, counted from the model's layer
-table (each configuration's reference module lists its layers).
+"""Operations and bytes of a round's core, counted by the configuration.
+
+``round_core`` is the one entry point.  A configuration's reference
+module may count its own round with ``round_core_flops(cfg, traffic)``
+and ``round_core_bytes(cfg, traffic)``; a module without them is an
+image classifier counted here from its layer table (``layers(cfg)``) and
+its image size.
 
 A layer is a dict with ``kind`` ("conv" or "dense") and its shapes:
 
@@ -11,7 +16,7 @@ normalisation, pooling, the softmax) is left out: it is under 1% of the
 convolutions of either model here."""
 from __future__ import annotations
 
-from typing import Dict, List
+from typing import Dict, List, Tuple
 
 
 def layer_macs(layer: Dict) -> int:
@@ -55,3 +60,22 @@ def round_core_bytes(n_params: int, cells: int, devices: int, batch: int,
     return (cells * n_params * param_bytes
             + cells * devices * batch * image_bytes
             + cells * devices * n_params * param_bytes)
+
+
+def round_core(ref, cfg: Dict, traffic: Dict) -> Tuple[int, int]:
+    """(FLOPs, bytes) of one execution of the round core: the module's
+    own counts where it defines them, else the layer table's FLOPs and
+    the bytes of f32 images and weights."""
+    t = traffic
+    if hasattr(ref, "round_core_flops"):
+        f = ref.round_core_flops(cfg, t)
+    else:
+        f = round_core_flops(ref.layers(cfg), t["num_cells"],
+                             t["num_devices"], t["tau"], t["batch_size"])
+    if hasattr(ref, "round_core_bytes"):
+        b = ref.round_core_bytes(cfg, t)
+    else:
+        b = round_core_bytes(cfg["parameters"], t["num_cells"],
+                             t["num_devices"], t["batch_size"],
+                             cfg["image_size"] ** 2 * cfg["channels"] * 4)
+    return f, b

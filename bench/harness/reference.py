@@ -1,12 +1,19 @@
 """The plain reference of a FedCGD round, followed over the checked rounds.
 
-Per round, for every device: one SGD step (Eq. 1, tau = 1) of softmax
-cross-entropy on the rows that device trained on, its loss, and its
-Eq. 10 sigma (the root-mean-square deviation of the per-sample gradients
-of the classifier head from their mean, formed sample by sample, on the
-round's weights without dropout); then Eq. 11 over the devices and
-Eq. 2, the mean of the device models that uploaded.  The forward pass is
-the configuration's reference module.
+Per round, for every device: one SGD step (Eq. 1, tau = 1) of the
+configuration's loss on the rows that device trained on, its loss, and
+its Eq. 10 sigma; then Eq. 11 over the devices and Eq. 2, the mean of
+the device models that uploaded.
+
+The loss and the sigma are the configuration's reference module's
+``loss(p, cfg, x, y, precision, key) -> scalar`` and
+``sigma(p, cfg, x, y, precision) -> scalar`` where it defines them, and
+otherwise the classifier's: the mean softmax cross-entropy of
+``features_logits``'s logits against the rows' labels, and the
+root-mean-square deviation of the per-sample gradients of the classifier
+head from their mean, formed sample by sample, on the round's weights
+without dropout (``default_loss``, ``default_sigma``).  Floating inputs
+take the reference's dtype; integer inputs (token ids) stay integers.
 
 Where the configuration drops activations, the masks come from the
 device's key as the program derives it (``device_keys``): a cell's key
@@ -37,29 +44,37 @@ def device_keys(cell_seed: int, num_devices: List[int]) -> list:
     return out
 
 
-def _device_step(ref, cfg, eta, dtype, precision, params, x, y, key):
-    _, step_key = jax.random.split(jax.random.wrap_key_data(key))
+def default_loss(ref, p, cfg, x, y, precision, key):
+    """Mean softmax cross-entropy of ``features_logits``'s logits."""
+    _, logits = ref.features_logits(p, cfg, x, precision, key)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    true = jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0]
+    return jnp.mean(lse - true)
 
-    def loss(p):
-        _, logits = ref.features_logits(p, cfg, x, precision, step_key)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        true = jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0]
-        return jnp.mean(lse - true)
 
-    l, g = jax.value_and_grad(loss)(params)
-    dev = jax.tree.map(lambda a, b: (a - jnp.asarray(eta, dtype) * b),
-                       params, g)
-    h, z = ref.features_logits(params, cfg, x, precision)
+def default_sigma(ref, p, cfg, x, y, precision):
+    """Eq. 10 over the classifier head: per-sample gradients h_i e_i^T."""
+    h, z = ref.features_logits(p, cfg, x, precision)
     e = jax.nn.softmax(z, axis=-1) - jax.nn.one_hot(y, z.shape[-1],
                                                     dtype=z.dtype)
     gi = h[:, :, None] * e[:, None, :]                 # [b, d, C]
     dev_sq = jnp.sum(jnp.square(gi - gi.mean(0)), axis=(1, 2))
-    return l, jnp.sqrt(jnp.mean(dev_sq)), dev
+    return jnp.sqrt(jnp.mean(dev_sq))
+
+
+def _device_step(ref, cfg, eta, dtype, precision, params, x, y, key):
+    _, step_key = jax.random.split(jax.random.wrap_key_data(key))
+    loss = getattr(ref, "loss", None) or partial(default_loss, ref)
+    sigma = getattr(ref, "sigma", None) or partial(default_sigma, ref)
+    l, g = jax.value_and_grad(
+        lambda p: loss(p, cfg, x, y, precision, step_key))(params)
+    dev = jax.tree.map(lambda a, b: (a - jnp.asarray(eta, dtype) * b),
+                       params, g)
+    return l, sigma(params, cfg, x, y, precision), dev
 
 
 @partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
-def _chunk(ref, cfg_key, eta, dtype, precision, params, xs, ys, keys, w):
-    cfg = dict(cfg_key)
+def _chunk(ref, cfg, eta, dtype, precision, params, xs, ys, keys, w):
     step = partial(_device_step, ref, cfg, eta, dtype, precision)
     l, s, dev = jax.vmap(step, in_axes=(None, 0, 0, 0))(params, xs, ys,
                                                        keys)
@@ -69,12 +84,27 @@ def _chunk(ref, cfg_key, eta, dtype, precision, params, xs, ys, keys, w):
     return l, s, contrib
 
 
-def _freeze(cfg: Dict):
-    return tuple(sorted((k, v) for k, v in cfg.items()
-                        if isinstance(v, (int, float, str, bool))))
+class Frozen(dict):
+    """A configuration as the jitted chunk's static argument: a dict that
+    hashes.  ``freeze`` makes its lists tuples and its groups ``Frozen``;
+    nothing may change it after."""
+
+    def __hash__(self):
+        return hash(tuple(sorted(self.items())))
 
 
-def follow(ref, cfg: Dict, w0, images: np.ndarray, labels: np.ndarray,
+def freeze(v):
+    """The configuration value ``v`` with every list a tuple and every
+    dict ``Frozen``, recursively: the whole configuration reaches the
+    reference module."""
+    if isinstance(v, dict):
+        return Frozen((k, freeze(x)) for k, x in v.items())
+    if isinstance(v, (list, tuple)):
+        return tuple(freeze(x) for x in v)
+    return v
+
+
+def follow(ref, cfg: Dict, w0, inputs: np.ndarray, labels: np.ndarray,
            rounds: List[Dict], eta: float, dtype=jnp.float32,
            precision=jax.lax.Precision.HIGHEST, chunk: int = 8) -> List[Dict]:
     """Follow the rounds from the weights ``w0``.
@@ -83,7 +113,7 @@ def follow(ref, cfg: Dict, w0, images: np.ndarray, labels: np.ndarray,
     (``device_keys``), "upload": [V] bool}.
     Returns per round {"loss", "dev_losses", "sigma_hat", "params"}
     (params after the round, as host numpy arrays)."""
-    cfg_key = _freeze(cfg)
+    frozen = freeze(cfg)
     p = jax.tree.map(lambda a: jnp.asarray(a, dtype), w0)
     out = []
     for r in rounds:
@@ -99,10 +129,12 @@ def follow(ref, cfg: Dict, w0, images: np.ndarray, labels: np.ndarray,
             w = np.concatenate([w, np.zeros(pad)])
         losses, sigmas, acc = [], [], None
         for s in range(0, len(takes), chunk):
-            t = takes[s:s + chunk]
+            x = inputs[takes[s:s + chunk]]
             l, sg, contrib = _chunk(
-                ref, cfg_key, float(eta), dtype, precision, p,
-                jnp.asarray(images[t], dtype), jnp.asarray(labels[t]),
+                ref, frozen, float(eta), dtype, precision, p,
+                jnp.asarray(x, dtype if np.issubdtype(x.dtype, np.floating)
+                            else None),
+                jnp.asarray(labels[takes[s:s + chunk]]),
                 jnp.asarray(keys[s:s + chunk]),
                 jnp.asarray(w[s:s + chunk], dtype))
             losses.append(np.asarray(l, np.float64))

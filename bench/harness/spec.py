@@ -3,13 +3,57 @@
 Under the benchmark's directory (``bench/``):
 
   configs/<config>.json   the model configuration as it is run
-  configs/<config>.py     its plain reference: weights, forward pass, layers
+  configs/<config>.py     its plain reference: weights, loss, sigma, counts
   traffic/<traffic>.json  the deployment: cells, devices, channel, schedule
   limits/<workload>.json  the limits of the numbers that decide ``correct``
   metrics/<metric>.py     one per-layer metric: ``read(ctx) -> float | None``
 
 A new cell is a new entry in ``BENCHMARK.json`` plus new files here; no
-existing file changes."""
+existing file changes.
+
+``configs/<config>.json`` always holds ``num_classes`` (P1's class axis:
+the labels of the rows) and ``reference_chunk`` (devices the reference
+runs at once), and then one of two kinds of input:
+
+  images (no ``inputs`` key)   ``train_images``, ``test_images``,
+                               ``image_size``, ``channels``, and
+                               ``parameters`` for the default byte count
+  ``"inputs": "tokens"``       ``train_sequences``, ``test_sequences``,
+                               ``seq_len``, ``vocab_size`` and ``zipf``
+                               (``harness.data.tokens``); the label is
+                               the row's topic, ``num_classes`` topics
+
+``configs/<config>.py`` defines ``init(key, cfg)`` (the weights, as the
+program's own parameter tree, made in one jitted call) and
+``program_model(cfg)`` (the program's ``repro.models.Model``), and four
+hooks, each optional:
+
+  loss(p, cfg, x, y, precision, key) -> scalar   one device's loss on
+      its rows x [b, ...] with labels y [b]; key draws any dropout masks
+  sigma(p, cfg, x, y, precision) -> scalar       its Eq. 10 sigma
+  round_core_flops(cfg, traffic) -> int          one execution of the
+      round core: its FLOPs
+  round_core_bytes(cfg, traffic) -> int          and the bytes it cannot
+      avoid moving through HBM
+
+A module without ``loss`` and ``sigma`` is a classifier: it defines
+``features_logits(p, cfg, x, precision, key=None) -> (features [b, d],
+logits [b, classes])``, and the reference takes the mean cross-entropy
+and the last-layer sigma (``harness.reference``).  One without the
+counts defines ``layers(cfg)``, its layer table (``harness.flops``), and
+is counted on f32 images.  A token configuration defines all four hooks.
+The reference's functions see the configuration with lists as tuples and
+groups as hashable dicts; ``init`` and ``program_model`` see it as read.
+
+``traffic/<traffic>.json`` holds the deployment's ``num_cells``,
+``num_devices``, ``available_prob``, ``batch_size``, ``tau``, ``eta``,
+``deadline_s``, ``scheduler``, ``scheduler_backend`` and
+``channel_seed`` (the program's ``FLConfig``), ``shards_per_device``,
+``total_bandwidth_hz``, ``warm_fix_sums`` ([lo, hi], the largest P1
+fix-sums warmed before the window) and ``trace_rounds``.
+``limits/<workload>.json`` holds ``{"limits": {number: {"limit": x}}}``
+over ``harness.check.NUMBERS``, ``mask_mismatches`` always among them.
+"""
 from __future__ import annotations
 
 import importlib.util

@@ -19,7 +19,7 @@ FAULTS = (None, "frozen", "half_batch", "flip_mask")
 class World:
     trainer: object
     weights: object            # the benchmark's weights, on the device
-    images: np.ndarray         # train images (host), as the reference reads them
+    inputs: np.ndarray         # train rows (host), as the reference reads them
     labels: np.ndarray
     recorder: data.RecordingArray
 
@@ -41,15 +41,32 @@ def _planted(model, fault: Optional[str]):
     return dataclasses.replace(model, loss_fn=planted)
 
 
+def _inputs(cfg: Dict, seed: int):
+    """(rows, labels, number of training rows) as the configuration's
+    ``inputs`` says: token sequences labelled by topic, or (the default)
+    images labelled by class."""
+    kind = cfg.get("inputs", "images")
+    if kind == "tokens":
+        n_train = cfg["train_sequences"]
+        x, y = data.tokens(seed, n_train + cfg["test_sequences"],
+                           cfg["num_classes"], cfg["seq_len"],
+                           cfg["vocab_size"], cfg["zipf"])
+        return x, y, n_train
+    if kind != "images":
+        raise ValueError(f"unknown inputs {kind!r}")
+    n_train = cfg["train_images"]
+    x, y = data.images(seed, n_train + cfg["test_images"], cfg["num_classes"],
+                       cfg["image_size"], cfg["channels"])
+    return x, y, n_train
+
+
 def build(cfg: Dict, ref, traffic: Dict, seed: int, obs: bool = False,
           fault: Optional[str] = None) -> World:
     from repro.data.datasets import ArrayDataset
     from repro.fl import FLConfig, MultiCellTrainer
     from repro.obs import ObsConfig
 
-    n_train, n_test = cfg["train_images"], cfg["test_images"]
-    x, y = data.images(seed, n_train + n_test, cfg["num_classes"],
-                       cfg["image_size"], cfg["channels"])
+    x, y, n_train = _inputs(cfg, seed)
     rec = x[:n_train].view(data.RecordingArray)
     train = ArrayDataset(rec, y[:n_train], cfg["num_classes"])
     test = ArrayDataset(x[n_train:], y[n_train:], cfg["num_classes"])

@@ -5,7 +5,9 @@
 The last tests drive the rest of a run at a small size with the chip
 check skipped: sound, it reads ``correct``; with each fault planted
 underneath the timed path, and with the bfloat16 control in the
-program's place, it does not."""
+program's place, it does not.  Besides the benchmark's own cells they
+drive a toy token configuration (``bench/toy``), added to a copy of the
+benchmark as new files only."""
 import os
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
@@ -22,6 +24,7 @@ import pytest  # noqa: E402
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
+TOY = BENCH / "toy"       # a token configuration's files, for these tests
 sys.path[:0] = [str(BENCH), str(ROOT / "src")]
 
 import jax  # noqa: E402
@@ -81,6 +84,134 @@ def test_parameter_counts(name, count):
 def test_round_core_bytes():
     assert flops.round_core_bytes(10, 2, 3, 4, 5) == 2 * 10 * 4 \
         + 2 * 3 * 4 * 5 + 2 * 3 * 10 * 4
+
+
+def test_toy_lm_counts_by_hand():
+    cfg, ref = spec.load_config("toy-lm", TOY)
+    traffic = spec.load_traffic("toy-lm-c1-v4", TOY)
+    # d 32, 4 heads and 2 KV heads of 8, d_ff 64, vocab 64, S 16, 2 layers
+    per_token = 2 * (32 * 8 * 8 + 4 * 8 * 32 + 3 * 32 * 64) + 32 * 64
+    scores = 2 * 2 * 2 * 16 * 16 * 4 * 8
+    fwd = 2 * 16 * per_token + scores
+    params = 2 * (2 * 32 + 32 * 8 * 8 + 4 * 8 * 32 + 3 * 32 * 64) \
+        + 2 * 64 * 32 + 32
+    shapes = jax.eval_shape(lambda k: ref.init(k, cfg), jax.random.key(0))
+    assert sum(x.size for x in jax.tree.leaves(shapes)) == params
+    # 4 devices of 4 sequences: one SGD step (3 passes) and the sigma's
+    # forward; the model read, the tokens read, 4 updates written
+    assert flops.round_core(ref, cfg, traffic) == (
+        16 * 4 * fwd, params * 4 + 16 * 16 * 4 + 4 * params * 4)
+
+
+# the classifier's defaults restated through the four hooks
+HOOKS = '''
+from harness import flops as _flops
+
+
+def loss(p, cfg, x, y, precision, key):
+    _, logits = features_logits(p, cfg, x, precision, key)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    true = jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0]
+    return jnp.mean(lse - true)
+
+
+def sigma(p, cfg, x, y, precision):
+    h, z = features_logits(p, cfg, x, precision)
+    e = jax.nn.softmax(z, axis=-1) - jax.nn.one_hot(y, z.shape[-1],
+                                                    dtype=z.dtype)
+    gi = h[:, :, None] * e[:, None, :]
+    dev_sq = jnp.sum(jnp.square(gi - gi.mean(0)), axis=(1, 2))
+    return jnp.sqrt(jnp.mean(dev_sq))
+
+
+def round_core_flops(cfg, t):
+    return _flops.round_core_flops(layers(cfg), t["num_cells"],
+                                   t["num_devices"], t["tau"],
+                                   t["batch_size"])
+
+
+def round_core_bytes(cfg, t):
+    return _flops.round_core_bytes(cfg["parameters"], t["num_cells"],
+                                   t["num_devices"], t["batch_size"],
+                                   cfg["image_size"] ** 2
+                                   * cfg["channels"] * 4)
+'''
+
+
+def _hooked(tmp_path, name):
+    """The configuration's module with ``HOOKS`` appended."""
+    d = tmp_path / "configs"
+    d.mkdir(exist_ok=True)
+    shutil.copy(BENCH / "configs" / f"{name}.json", d)
+    (d / f"{name}.py").write_text(
+        (BENCH / "configs" / f"{name}.py").read_text() + HOOKS)
+    return spec.load_config(name, tmp_path)
+
+
+@pytest.mark.parametrize("name,workload",
+                         [("paper-cnn-cifar10", "cnn-c1-v64"),
+                          ("resnet18-gn-cifar100", "resnet18-c1-v20")])
+def test_hooks_restating_the_defaults_count_the_same(tmp_path, name,
+                                                     workload):
+    cfg, plain = _config(name)
+    _, hooked = _hooked(tmp_path, name)
+    w = spec.find_workload(spec.load_benchmark(), workload)
+    traffic = spec.load_traffic(w["traffic"])
+    assert flops.round_core(hooked, cfg, traffic) == \
+        flops.round_core(plain, cfg, traffic)
+    progs = [["jit_one_cell(3)", 0, 45_550_000]]
+    for m in ("core_roofline", "round_mfu"):
+        read = spec.load_module(BENCH / "metrics" / f"{m}.py", m).read
+        got = [read(cell.Context(
+            cfg=cfg, ref=ref, traffic=traffic, rounds=345, window_s=30.01,
+            device={"platform": "tpu", "kind": "TPU v5 lite"},
+            trace={"device_programs": progs})) for ref in (plain, hooked)]
+        assert got[0] is not None and got[0] == got[1]
+
+
+def test_hooks_restating_the_defaults_follow_the_same(tmp_path):
+    cfg, plain = _config("paper-cnn-cifar10")
+    _, hooked = _hooked(tmp_path, "paper-cnn-cifar10")
+    x, y = data.images(5, 64, 10)
+    w0 = jax.device_get(plain.init(jax.random.key(5), cfg))
+    rng = np.random.default_rng(5)
+    keys = reference.device_keys(5, [4, 4])
+    rounds = [{"takes": rng.choice(64, (4, 4)), "keys": k,
+               "upload": np.array([1, 0, 1, 1], bool)} for k in keys]
+    a, b = (reference.follow(ref, cfg, w0, x, y, rounds, 0.01, chunk=2)
+            for ref in (plain, hooked))
+    for ra, rb in zip(a, b):
+        assert (ra["loss"], ra["sigma_hat"]) == (rb["loss"], rb["sigma_hat"])
+        np.testing.assert_array_equal(ra["dev_losses"], rb["dev_losses"])
+        for pa, pb in zip(jax.tree.leaves(ra["params"]),
+                          jax.tree.leaves(rb["params"])):
+            np.testing.assert_array_equal(pa, pb)
+
+
+def test_toy_lm_reference_follows_the_program():
+    """The toy's reference loss and sigma against the program's model on
+    the same weights and tokens (the program's own batch layout)."""
+    from repro.core.estimation import sigma_hat_lastlayer
+    cfg, ref = spec.load_config("toy-lm", TOY)
+    w = jax.jit(lambda k: ref.init(k, cfg))(jax.random.key(3))
+    model = ref.program_model(cfg)
+    assert jax.tree.structure(jax.eval_shape(model.init, jax.random.key(0))) \
+        == jax.tree.structure(w)
+    x, y = data.tokens(3, 8, 4, cfg["seq_len"], cfg["vocab_size"], 1.1)
+    toks = jax.numpy.asarray(x)
+    batch = {"tokens": toks,
+             "targets": jax.numpy.concatenate([toks[:, 1:], toks[:, -1:]], 1),
+             "loss_mask": jax.numpy.ones(x.shape).at[:, -1].set(0.0)}
+    hi = jax.lax.Precision.HIGHEST
+    with jax.default_matmul_precision("highest"):
+        prog_loss = float(model.loss_fn(w, batch)[0])
+        logits = model.forward(w, batch)[0][:, -1]
+        prog_sigma = float(sigma_hat_lastlayer(
+            jax.numpy.ones((8, 1)), logits, batch["targets"][:, -1]))
+    assert float(ref.loss(w, cfg, toks, y, hi, None)) == \
+        pytest.approx(prog_loss, rel=1e-5)
+    assert float(ref.sigma(w, cfg, toks, y, hi)) == \
+        pytest.approx(prog_sigma, rel=1e-5)
 
 
 # ---------------------------------------------------------------------------
@@ -165,6 +296,39 @@ def test_images_deterministic_from_seed():
     np.testing.assert_array_equal(a[1], b[1])
     assert not np.array_equal(a[0], c[0])
     assert np.bincount(a[1], minlength=10).tolist() == [7] * 4 + [6] * 6
+
+
+def test_tokens_deterministic_from_seed():
+    big = 2 ** 33 + 5
+    a = data.tokens(big, 400, 4, 64, 50, 1.1)
+    b = data.tokens(big, 400, 4, 64, 50, 1.1)
+    c = data.tokens(big + 1, 400, 4, 64, 50, 1.1)
+    assert a[0].shape == (400, 64) and a[0].dtype == np.int32
+    assert a[1].dtype == np.int32
+    np.testing.assert_array_equal(a[0], b[0])
+    np.testing.assert_array_equal(a[1], b[1])
+    assert not np.array_equal(a[0], c[0])
+    assert np.bincount(a[1], minlength=4).tolist() == [100] * 4
+    assert a[0].min() >= 0 and a[0].max() < 50
+    # each topic's law: Zipf(1.1) over a ranking of its own
+    law = np.arange(1, 51) ** -1.1
+    law /= law.sum()
+    freq = np.stack([np.bincount(a[0][a[1] == t].ravel(), minlength=50)
+                     / 6400 for t in range(4)])
+    for f in freq:
+        np.testing.assert_allclose(np.sort(f)[::-1][:3], law[:3], atol=0.02)
+    for i in range(4):
+        for j in range(i):
+            assert 0.5 * np.abs(freq[i] - freq[j]).sum() > 0.3
+
+
+def test_freeze_keeps_lists_and_groups():
+    cfg = {"a": 1, "l": [1, [2, 3]], "g": {"x": [4], "y": {"z": "w"}}}
+    f = reference.freeze(cfg)
+    assert f == {"a": 1, "l": (1, (2, 3)), "g": {"x": (4,), "y": {"z": "w"}}}
+    assert isinstance(f["g"]["y"], dict)
+    assert hash(f) == hash(reference.freeze(dict(reversed(cfg.items()))))
+    assert hash(f) != hash(reference.freeze(dict(cfg, g={"x": [5]})))
 
 
 def test_shards_deterministic_and_cover_every_row():
@@ -302,17 +466,31 @@ SMALL = {
 }
 
 
+# the toy token cell: its files are new, its entries added to a copy
+TOY_CELL = {"name": "toy-lm-c1-v4", "config": "toy-lm",
+            "traffic": "toy-lm-c1-v4", "chips": 1}
+CELLS = list(SMALL) + [TOY_CELL["name"]]
+
+
 @pytest.fixture(scope="module")
 def small_root(tmp_path_factory):
-    """A copy of the benchmark with every cell cut as ``SMALL`` says."""
+    """A copy of the benchmark with every cell cut as ``SMALL`` says, and
+    the toy token cell added to it as new files."""
     root = tmp_path_factory.mktemp("small")
-    shutil.copy(ROOT / "BENCHMARK.json", root)
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "toy-lm"})
+    bench["workloads"].append(TOY_CELL)
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
     shutil.copytree(BENCH, root / "bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
     for files in SMALL.values():
         for rel, over in files.items():
             p = root / "bench" / rel
             p.write_text(json.dumps(dict(json.loads(p.read_text()), **over)))
+    for f in TOY.rglob("*.*"):
+        new = root / "bench" / f.relative_to(TOY)
+        assert not new.exists(), new
+        shutil.copy(f, new)
     return root
 
 
@@ -335,6 +513,13 @@ def test_sound_run_is_correct_and_prints_every_metric(small_root):
     assert r["check"]["mask_mismatches"]["value"] == 0
 
 
+def test_toy_lm_sound_run_is_correct(small_root):
+    r = _run(small_root, TOY_CELL["name"])
+    assert r["correct"], r["check"]
+    assert set(r["metrics"]) == {"rounds_per_s", "setup_s"}
+    assert r["attempted"] > 0
+
+
 def test_traced_run_reports_per_layer_metrics(small_root):
     r = _run(small_root, traced=True)
     assert r["correct"], r["check"]
@@ -347,7 +532,7 @@ def test_traced_run_reports_per_layer_metrics(small_root):
     assert set(r["breakdown"]) == {"device_ops", "idle_gaps"}
 
 
-@pytest.mark.parametrize("workload", list(SMALL))
+@pytest.mark.parametrize("workload", CELLS)
 @pytest.mark.parametrize("fault", ["frozen", "half_batch", "flip_mask"])
 def test_planted_fault_is_not_correct(small_root, workload, fault):
     r = _run(small_root, workload, fault=fault)
@@ -358,7 +543,7 @@ def test_planted_fault_is_not_correct(small_root, workload, fault):
 # the control: the reference in bfloat16, put in the program's place
 
 
-@pytest.mark.parametrize("workload", list(SMALL))
+@pytest.mark.parametrize("workload", CELLS)
 def test_control_is_not_correct(small_root, workload):
     import calibrate
     b = small_root / "bench"
