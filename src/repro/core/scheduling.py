@@ -153,15 +153,14 @@ def greedy_scheduling(prob: Problem) -> Schedule:
 # Algorithm 2: Fix-Sum Coordinate Descent
 
 
-def fscd(prob: Problem, max_inner: int = 200) -> Schedule:
+def fscd_fix_sums(prob: Problem, max_inner: int = 200):
+    """Algorithm 2's coordinate descent at each fix-sum S = S_max, ...,
+    1 in turn, lazily: yields ``(S, mask, w_cur, iterations)`` for each
+    S, the descent started from the S devices of least bandwidth."""
     V = prob.num_devices
     feas = prob.feasible()
     bw = np.where(feas, prob.min_bw, np.inf)
     order = np.argsort(bw, kind="stable")       # least bandwidth first
-    sigma_b = prob.sigma / np.sqrt(prob.batch_size)
-
-    best_mask, best_obj = np.zeros(V, bool), np.inf
-    total_iters = 0
     # the largest feasible S: greedy-fill by least bandwidth
     cum = np.cumsum(bw[order])
     S_max = int((cum <= prob.total_bw + 1e-9).sum())
@@ -173,8 +172,9 @@ def fscd(prob: Problem, max_inner: int = 200) -> Schedule:
         used = float(bw[order[:S]].sum())
         w_cur = WE.wemd_of_set(prob.p_dev, mask, prob.global_dist,
                                prob.class_weights)
+        iters = 0
         for _ in range(max_inner):
-            total_iters += 1
+            iters += 1
             in_idx = np.flatnonzero(mask)
             out_idx = np.flatnonzero(~mask & feas)
             if len(out_idx) == 0:
@@ -193,9 +193,18 @@ def fscd(prob: Problem, max_inner: int = 200) -> Schedule:
             p_sum += prob.p_dev[vj] - prob.p_dev[vi]
             used = float(bw_new[i, j])
             w_cur = float(w_swap[i, j])
+        yield S, mask, w_cur, iters
+
+
+def fscd(prob: Problem, max_inner: int = 200) -> Schedule:
+    sigma_b = prob.sigma / np.sqrt(prob.batch_size)
+    best_mask, best_obj = np.zeros(prob.num_devices, bool), np.inf
+    total_iters = 0
+    for S, mask, w_cur, iters in fscd_fix_sums(prob, max_inner):
+        total_iters += iters
         obj = w_cur + sigma_b / np.sqrt(S)
         if obj < best_obj:
-            best_obj, best_mask = obj, mask.copy()
+            best_obj, best_mask = obj, mask
         # early exit (paper line 10): no smaller S can do better
         if S > 1 and w_cur + sigma_b / np.sqrt(S) <= sigma_b / np.sqrt(S - 1):
             break

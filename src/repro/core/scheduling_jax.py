@@ -9,9 +9,9 @@ solver over a problem axis:
     each iteration adds at most one device per problem.
   * FSCD (Algorithm 2) is vectorized over problems *and* over the
     fix-sum axis: every (problem, S) pair of the outer loop is an
-    independent lane of a coordinate-descent while-loop, run in short
-    *phases* — after each phase the still-unconverged lanes are
-    compacted on the host so the batch shrinks as lanes converge.  The
+    independent lane of one coordinate-descent while-loop, which runs
+    until no lane is live (each lane converged or at ``max_inner``), in
+    one dispatch and one pull; converged lanes stay frozen.  The
     swap matrix is scanned on member-compacted rows in float32 and the
     top-K candidates re-evaluated with numpy's float64 op order (the
     whole matrix when more than K candidates may tie the minimum), with
@@ -167,9 +167,16 @@ def _gs_batch_impl(p_dev, gd, cw, sigma, batch_size, min_bw, total_bw,
 # so the member-row permutation cannot change the selected swap.
 
 
-def _fscd_phase_impl(p_dev, gd, cw, bw, feas, total_bw, s_lane,
-                     members, mask, p_sum, used, w_cur, act, iters,
-                     max_inner, phase_steps, use_pallas=False):
+def _fscd_descent_impl(p_dev, gd, cw, bw, feas, total_bw, lane_b, s_lane,
+                       members, mask, p_sum, used, w_cur, max_inner,
+                       use_pallas=False):
+    """Every lane's descent to convergence (or ``max_inner`` steps) in
+    one loop.  The per-problem stacks [B, ...] are gathered to lanes by
+    ``lane_b``; lanes with ``s_lane`` 0 are padding and never live.
+    Returns each lane's mask, WEMD and iterations, and the loop's step
+    count (the largest lane iteration count)."""
+    p_dev, gd, cw, bw, feas, total_bw = (
+        x[lane_b] for x in (p_dev, gd, cw, bw, feas, total_bw))
     L, V, C = p_dev.shape
     R = members.shape[1]
     sf = s_lane.astype(p_dev.dtype)
@@ -177,8 +184,7 @@ def _fscd_phase_impl(p_dev, gd, cw, bw, feas, total_bw, s_lane,
     valid_r = jnp.arange(R)[None, :] < s_lane[:, None]
 
     def cond(carry):
-        live = carry[5] & (carry[6] < max_inner)
-        return live.any() & (carry[7] < phase_steps)
+        return (carry[5] & (carry[6] < max_inner)).any()
 
     K = min(16, R * V)
     f32 = jnp.float32
@@ -190,7 +196,10 @@ def _fscd_phase_impl(p_dev, gd, cw, bw, feas, total_bw, s_lane,
         p_mem = jnp.take_along_axis(p_dev, members[:, :, None], 1)  # [L,R,C]
         bw_mem = jnp.take_along_axis(bw, members, 1)
         bw_new = (used[:, None, None] - bw_mem[:, :, None]) + bw[:, None, :]
-        ok = (valid_r[:, :, None] & (~mask & feas)[:, None, :]
+        # converged lanes offer no candidate, so they never force the
+        # whole-matrix re-evaluation below
+        ok = (live[:, None, None] & valid_r[:, :, None]
+              & (~mask & feas)[:, None, :]
               & (bw_new <= total_bw[:, None, None] + 1e-9)).reshape(L, R * V)
         # float32 scan of the full [R, V] swap matrix, then the float64
         # re-evaluation (numpy's op order) of the K best candidates, or of
@@ -258,10 +267,11 @@ def _fscd_phase_impl(p_dev, gd, cw, bw, feas, total_bw, s_lane,
         w_cur = jnp.where(upd, wmin, w_cur)
         return members, mask, p_sum, used, w_cur, upd, iters, step + 1
 
-    init = (members, mask, p_sum, used, w_cur, act, iters,
-            jnp.asarray(0, jnp.int32))
-    out = jax.lax.while_loop(cond, body, init)
-    return out[:7]
+    init = (members, mask, p_sum, used, w_cur, s_lane > 0,
+            jnp.zeros((L,), jnp.int32), jnp.asarray(0, jnp.int32))
+    _, mask, _, _, w_cur, _, iters, steps = jax.lax.while_loop(cond, body,
+                                                               init)
+    return mask, w_cur, iters, steps
 
 
 _JIT_CACHE: dict = {}
@@ -328,12 +338,12 @@ def _bucket(n: int) -> int:
 
 def solve_many_fscd(problems: Sequence[SCH.Problem],
                     max_inner: int = 200,
-                    phase_steps: int = 4,
                     pallas: bool | None = None,
                     obs=DISABLED) -> List[SCH.Schedule]:
-    """FSCD over the batch in phases of ``phase_steps`` descent steps:
-    one dispatch and one device->host pull (``obs``'s ``schedule.pull``
-    span) per phase."""
+    """FSCD over the batch: every (problem, S) lane descends to
+    convergence in one dispatch, read back in one device->host pull
+    (``obs``'s ``schedule.pull`` span).  The loop's step count feeds
+    counter ``sched.fscd_steps_total``."""
     from repro.core import wemd as WE
 
     st = _stack(problems)
@@ -348,60 +358,42 @@ def solve_many_fscd(problems: Sequence[SCH.Problem],
     for b in range(B):
         cum = np.cumsum(bw_p[b][order_p[b]])
         s_max[b] = int((cum <= st["total_bw"][b] + 1e-9).sum())
-    lane_b = np.concatenate([np.full(s_max[b], b, int) for b in range(B)]
-                            or [np.zeros(0, int)])
-    s_lane = np.concatenate([np.arange(s_max[b], 0, -1) for b in range(B)]
-                            or [np.zeros(0, int)])
-    L = len(lane_b)
+    L = int(s_max.sum())
 
-    masks = np.zeros((L, V), bool)
-    w_cur = np.zeros(L)
-    iters = np.zeros(L, np.int32)
     if L:
+        # padded to a stable lane count; padding lanes hold S = 0
+        Lp = _bucket(L)
+        lane_b = np.zeros(Lp, np.int32)
+        lane_b[:L] = np.repeat(np.arange(B), s_max)
+        s_lane = np.zeros(Lp, int)
+        s_lane[:L] = np.concatenate([np.arange(s_max[b], 0, -1)
+                                     for b in range(B)])
         S_cap = int(s_lane.max())
-        members = np.zeros((L, S_cap), np.int32)
-        p_sum = np.zeros((L, C))
-        used = np.zeros(L)
-        act = np.ones(L, bool)
+        members = np.zeros((Lp, S_cap), np.int32)
+        masks_in = np.zeros((Lp, V), bool)
+        p_sum = np.zeros((Lp, C))
+        used = np.zeros(Lp)
+        w_in = np.zeros(Lp)
         for l in range(L):
             b, S = lane_b[l], int(s_lane[l])
             members[l, :S] = order_p[b][:S]
-            masks[l, order_p[b][:S]] = True
-            p_sum[l] = st["p_dev"][b][masks[l]].sum(axis=0)
+            masks_in[l, order_p[b][:S]] = True
+            p_sum[l] = st["p_dev"][b][masks_in[l]].sum(axis=0)
             used[l] = float(bw_p[b][order_p[b][:S]].sum())
-            w_cur[l] = WE.wemd_of_set(st["p_dev"][b], masks[l], st["gd"][b],
-                                      st["cw"][b])
-        # lane-indexed constants
-        p_dev_l, gd_l, cw_l = (st["p_dev"][lane_b], st["gd"][lane_b],
-                               st["cw"][lane_b])
-        bw_l, feas_l = bw_p[lane_b], feas_p[lane_b]
-        tot_l = st["total_bw"][lane_b]
+            w_in[l] = WE.wemd_of_set(st["p_dev"][b], masks_in[l],
+                                     st["gd"][b], st["cw"][b])
 
-        # phase-chunked descent: run every live lane a few steps, pull
-        # the still-live set to the host, compact, repeat — so the batch
-        # shrinks as lanes converge instead of spinning until the
-        # slowest lane is done
-        alive = np.arange(L)
         up = _use_pallas(pallas)
         with jax.enable_x64(True):
-            fn = _jitted(f"fscd_phase_p{int(up)}", _fscd_phase_impl,
-                         static_argnums=(14, 15, 16))
-            while alive.size:
-                n = alive.size
-                sel = np.concatenate(
-                    [alive, np.full(_bucket(n) - n, alive[0])])
-                act_in = act[sel]
-                act_in[n:] = False
-                out = fn(p_dev_l[sel], gd_l[sel], cw_l[sel], bw_l[sel],
-                         feas_l[sel], tot_l[sel], s_lane[sel],
-                         members[sel], masks[sel], p_sum[sel], used[sel],
-                         w_cur[sel], act_in, iters[sel],
-                         int(max_inner), int(phase_steps), up)
-                o = [x[:n] for x in obs.pull(out, "schedule.pull")]
-                members[alive], masks[alive], p_sum[alive] = o[0], o[1], o[2]
-                used[alive], w_cur[alive] = o[3], o[4]
-                act[alive], iters[alive] = o[5], o[6]
-                alive = alive[o[5] & (o[6] < max_inner)]
+            fn = _jitted(f"fscd_p{int(up)}", _fscd_descent_impl,
+                         static_argnums=(13, 14))
+            out = fn(st["p_dev"], st["gd"], st["cw"], bw_p, feas_p,
+                     st["total_bw"], lane_b, s_lane, members, masks_in,
+                     p_sum, used, w_in, int(max_inner), up)
+            masks_o, w_o, iters_o, steps = obs.pull(out, "schedule.pull")
+        masks, w_cur, iters = masks_o[:L], w_o[:L], iters_o[:L]
+        if obs.enabled:
+            obs.metrics.counter("sched.fscd_steps_total").inc(int(steps))
 
     # replay the numpy outer loop (best tracking + early exit) exactly
     out: List[SCH.Schedule] = []

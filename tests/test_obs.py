@@ -18,8 +18,8 @@ Key contracts:
     scheduler's too) — steady rounds at a fixed shape add none;
   * enabled spans are profiler annotations ``fl.<name>`` on the host's
     timeline, each ``*.pull`` inside its phase; obs off writes none;
-  * the scheduler's device->host pulls go through ``Obs.pull``: one for
-    GS, one per FSCD phase, with unchanged masks.
+  * the scheduler's device->host pulls go through ``Obs.pull``: one a
+    ``solve_many`` call for GS and for FSCD, with unchanged masks.
 """
 import dataclasses
 import math
@@ -446,39 +446,24 @@ def _sched_problems(rng, n, V, C=6):
 
 
 @pytest.mark.parametrize("algorithm", ["gs", "fscd"])
-def test_scheduler_pulls_one_per_dispatch(monkeypatch, algorithm):
-    """GS pulls once per ``solve_many`` call, FSCD once per phase, each
-    under ``schedule.pull``; masks equal numpy's with obs on and off."""
+def test_scheduler_pulls_one_per_dispatch(sched_dispatches, algorithm):
+    """GS and FSCD each dispatch once and pull once per ``solve_many``
+    call, under ``schedule.pull``; masks equal numpy's with obs on and
+    off."""
     from repro.core import scheduling as S
-    from repro.core import scheduling_jax as SJ
-    dispatches = []
-    real = SJ._jitted
-
-    def counting(name, fn, static_argnums=()):
-        jitted = real(name, fn, static_argnums)
-
-        def call(*args):
-            dispatches.append(name)
-            return jitted(*args)
-        return call
-
-    monkeypatch.setattr(SJ, "_jitted", counting)
     probs = _sched_problems(np.random.default_rng(11), 6, 16)
     want = S.solve_many(probs, algorithm, backend="numpy")
     obs = Obs(enabled=True, sinks=[MemorySink(4)])
     with obs.span("round"):
         got = S.solve_many(probs, algorithm, backend="jax", obs=obs)
-    n_dispatch = len(dispatches)
+    n_dispatch = len(sched_dispatches)
     off = S.solve_many(probs, algorithm, backend="jax", obs=DISABLED)
     for w, g, o in zip(want, got, off):
         assert np.array_equal(w.mask, g.mask)
         assert np.array_equal(w.mask, o.mask)
         assert w.iterations == g.iterations == o.iterations
     pulls = obs.metrics.histogram("span.schedule.pull").count
-    if algorithm == "gs":
-        assert pulls == n_dispatch == 1
-    else:
-        assert pulls == n_dispatch > 1
+    assert pulls == n_dispatch == 1
     assert obs.round_record({"round": 0})["sched_pulls"] == pulls
 
 

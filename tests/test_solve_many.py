@@ -98,6 +98,44 @@ def test_solve_many_matches_numpy_on_ties(V, C, max_classes, algorithm,
         assert np.array_equal(numpy_fn(p).mask, g.mask), (algorithm, t)
 
 
+@pytest.mark.parametrize("case,B,V,max_inner", [
+    ("spread", 6, 16, 200),       # lanes converge > 4 steps apart
+    ("padded", 4, 64, 200),       # more lanes than one bucket, padded
+    ("capped", 6, 16, 3),         # lanes stopped by max_inner
+])
+def test_fscd_one_dispatch_matches_numpy(sched_dispatches, case, B, V,
+                                         max_inner):
+    """Every lane descends in one dispatch and one pull; masks and
+    iterations equal numpy's, and the loop runs exactly as many steps as
+    the slowest lane's iterations."""
+    from repro.core import scheduling_jax as SJ
+    from repro.obs import Obs
+
+    rng = np.random.default_rng(2024 + V)
+    probs = [random_problem(rng, V) for _ in range(B)]
+    lane_iters = [it for p in probs
+                  for *_, it in S.fscd_fix_sums(p, max_inner)]
+    L = len(lane_iters)
+    if case == "spread":
+        assert max(lane_iters) - min(lane_iters) > 4
+    elif case == "padded":
+        assert SJ._bucket(L) > L > SJ._LANE_BUCKET
+    else:
+        assert min(lane_iters) < max(lane_iters) == max_inner
+
+    obs = Obs(enabled=True)
+    got = S.solve_many(probs, "fscd", backend="jax", max_inner=max_inner,
+                       obs=obs)
+    for t, (p, g) in enumerate(zip(probs, got)):
+        e = S.fscd(p, max_inner)
+        assert np.array_equal(e.mask, g.mask), (case, t)
+        assert e.iterations == g.iterations, (case, t)
+    m = obs.metrics
+    assert len(sched_dispatches) == 1
+    assert m.histogram("span.schedule.pull").count == 1
+    assert m.counter("sched.fscd_steps_total").value == max(lane_iters)
+
+
 def test_argmin_tied_takes_lowest_index_within_margin():
     w = np.array([3.0, 1.0 + 5e-13, 2.0, 1.0, 1.0 - 5e-13])
     assert S.argmin_tied(w) == 1

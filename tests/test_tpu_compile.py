@@ -97,27 +97,26 @@ def test_gs_program_compiles(one_chip, compiled_kernels):
 
 
 def test_fscd_phase_program_compiles(one_chip, compiled_kernels):
-    from repro.core.scheduling_jax import _fscd_phase_impl
-    L, V, C, R = 32, 24, 10, 16
+    from repro.core.scheduling_jax import _fscd_descent_impl
+    B, L, V, C, R = 2, 32, 24, 10, 16
     f64, i32 = jnp.float64, jnp.int32
     with jax.enable_x64(True):
-        args = (_spec(one_chip, (L, V, C), f64),      # p_dev
-                _spec(one_chip, (L, C), f64),         # gd
-                _spec(one_chip, (L, C), f64),         # cw
-                _spec(one_chip, (L, V), f64),         # bw
-                _spec(one_chip, (L, V), bool),        # feas
-                _spec(one_chip, (L,), f64),           # total_bw
+        args = (_spec(one_chip, (B, V, C), f64),      # p_dev
+                _spec(one_chip, (B, C), f64),         # gd
+                _spec(one_chip, (B, C), f64),         # cw
+                _spec(one_chip, (B, V), f64),         # bw
+                _spec(one_chip, (B, V), bool),        # feas
+                _spec(one_chip, (B,), f64),           # total_bw
+                _spec(one_chip, (L,), i32),           # lane_b
                 _spec(one_chip, (L,), jnp.int64),     # s_lane
                 _spec(one_chip, (L, R), i32),         # members
                 _spec(one_chip, (L, V), bool),        # mask
                 _spec(one_chip, (L, C), f64),         # p_sum
                 _spec(one_chip, (L,), f64),           # used
-                _spec(one_chip, (L,), f64),           # w_cur
-                _spec(one_chip, (L,), bool),          # act
-                _spec(one_chip, (L,), i32))           # iters
-        compiled = jax.jit(_fscd_phase_impl,
-                           static_argnums=(14, 15, 16)).lower(
-            *args, 200, 4, True).compile()
+                _spec(one_chip, (L,), f64))           # w_cur
+        compiled = jax.jit(_fscd_descent_impl,
+                           static_argnums=(13, 14)).lower(
+            *args, 200, True).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
